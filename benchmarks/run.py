@@ -33,7 +33,8 @@ CSV and writes machine-readable results to results/benchmarks/.
   ablations  model-accounting options (act_reread, idle-PE, load hops)
   backends   grid_sweep numpy-float64 vs fused Pallas sweep kernel
   precision  bitwidth DSE: (h, w, act_bits, weight_bits) design points
-  kernels    Pallas kernel microbenches (interpret mode)
+  kernels    Pallas kernel microbenches (Mosaic on TPU, interpret mode
+             elsewhere; each row is named after the backend that ran it)
 
 ``--quick`` runs the reduced capacity sweep, the serving-scenario sweep,
 the traffic, kv, fleet, search, obs and windowed stages, writing
@@ -223,10 +224,9 @@ def scenarios_bench(quick: bool = False):
     reps = 1 if quick else 3
     hs = grid_axes()[::4]                     # 8x8 = 64 configs
     kw = dict(hs=hs, ws=hs)
-    s_fu, us_fu = _timeit(lambda: scenario_sweep(nw, block_c=64, **kw),
-                          n=reps)
+    s_fu, us_fu = _timeit(lambda: scenario_sweep(nw, **kw), n=reps)
     s_lp, us_lp = _timeit(
-        lambda: scenario_sweep(nw, fused=False, block_c=64, **kw), n=reps)
+        lambda: scenario_sweep(nw, fused=False, **kw), n=reps)
     s_np, us_np = _timeit(lambda: scenario_sweep(nw, backend="numpy", **kw),
                           n=reps)
     rel = 0.0
@@ -742,23 +742,22 @@ def kernels():
     import jax.numpy as jnp
     from repro.kernels import ops
     from repro.core.cnn_zoo import get_workloads
+    kb = ops.kernel_backend()
     rng = np.random.default_rng(0)
     a = jnp.asarray(rng.normal(size=(256, 256)), jnp.float32)
     w = jnp.asarray(rng.normal(size=(256, 256)), jnp.float32)
     for sched in ("ws", "os"):
         _, us = _timeit(
-            lambda s=sched: ops.matmul(a, w, schedule=s,
-                                       interpret=True).block_until_ready(),
+            lambda s=sched: ops.matmul(a, w,
+                                       schedule=s).block_until_ready(),
             n=1)
-        _emit(f"kernel_ws_matmul_{sched}_interpret", us, "256x256x256")
+        _emit(f"kernel_ws_matmul_{sched}_{kb}", us, "256x256x256")
     layers = np.asarray(get_workloads("alexnet"), np.float32)
     cfgs = np.stack(np.meshgrid(np.arange(16, 144, 8), np.arange(16, 144, 8),
                                 indexing="ij"), -1).reshape(-1, 2)[:256]
     _, us = _timeit(
-        lambda: ops.sweep(jnp.asarray(cfgs, jnp.float32),
-                          jnp.asarray(layers),
-                          interpret=True).block_until_ready(), n=1)
-    _emit("kernel_dse_eval_interpret", us,
+        lambda: ops.sweep(cfgs, layers).block_until_ready(), n=1)
+    _emit(f"kernel_dse_eval_{kb}", us,
           f"{len(cfgs)}cfgs_x_{len(layers)}layers")
 
 
@@ -1144,6 +1143,8 @@ def main() -> None:
                              "BENCH_fleet.json, BENCH_search.json, "
                              "BENCH_obs.json and BENCH_windowed.json)")
     args = parser.parse_args()
+    from repro.kernels.ops import use_compile_cache
+    use_compile_cache()
     print("name,us_per_call,derived")
     if args.quick:
         _stage(graph_quick)

@@ -97,29 +97,23 @@ def _grid_sweep_numpy(workloads, hs, ws, H, W, **model_kw):
                        ub_bw_bits=grid(m.ub_bandwidth_bits))
 
 
-def _pallas_eval_configs(workloads, cfgs, block_c=128, **model_kw):
+def _pallas_eval_configs(workloads, cfgs, block_c=None, **model_kw):
     """Evaluate an arbitrary (C, 2) config list on the fused Pallas sweep
     kernel, returning a dict of per-config metric columns.
 
-    The config list is auto-padded up to a multiple of the kernel block
-    (repeating the last design point) and unpadded afterwards; off-TPU the
-    kernel runs in interpret mode (kernels/ops handles the fallback).
+    `kernels.ops` pads the config list to the kernel block and slices the
+    result back; off-TPU the kernel runs in interpret mode.
     """
-    import jax.numpy as jnp
-
     from repro.kernels import ops
-    from repro.kernels.dse_eval import OUT_COLS, pad_configs
+    from repro.kernels.dse_eval import OUT_COLS
 
-    cfgs, C = pad_configs(cfgs, block_c)
     layers = np.asarray(
         [(m, k, n, g, r) for (m, k, n, g, r) in workloads], np.float32)
-    out = np.asarray(ops.sweep(jnp.asarray(cfgs, jnp.float32),
-                               jnp.asarray(layers), block_c=block_c,
-                               **model_kw))[:C]
+    out = np.asarray(ops.sweep(cfgs, layers, block_c=block_c, **model_kw))
     return {k: out[:, j] for j, k in enumerate(OUT_COLS)}
 
 
-def _grid_sweep_pallas(workloads, hs, ws, H, W, block_c=128, **model_kw):
+def _grid_sweep_pallas(workloads, hs, ws, H, W, block_c=None, **model_kw):
     """Dispatch the whole grid to the fused Pallas sweep kernel."""
     cfgs = np.stack([H.reshape(-1), W.reshape(-1)], axis=1)
     col = {k: v.reshape(H.shape) for k, v in _pallas_eval_configs(
@@ -310,18 +304,14 @@ def equal_pe_sweep(model_workloads: Dict[str, Sequence[Workload]],
 
 # ---------------------------------------------------- serving-scenario DSE --
 
-# Padding row for batched layer tables: groups*repeats == 0 zeroes every
-# summed counter in the kernel; the maxed bandwidth terms are masked on the
-# same weight (see kernels/dse_eval.py).
-PAD_LAYER = (1.0, 1.0, 1.0, 0.0, 0.0)
-
 _SWEEP_KEYS = ("cycles", "energy", "utilization", "m_ub", "m_inter_pe",
                "m_aa", "ub_bw_bits")
 
 
 def pad_layer_sets(workload_lists: Sequence[Sequence[Workload]]):
     """Pack ragged per-scenario workload lists into one (S, Lmax, 5) float32
-    tensor, padding with `PAD_LAYER` rows."""
+    tensor, padding with `kernels.dse_eval.PAD_LAYER` rows."""
+    from repro.kernels.dse_eval import PAD_LAYER
     L = max(len(wls) for wls in workload_lists)
     out = np.empty((len(workload_lists), L, 5), np.float32)
     for i, wls in enumerate(workload_lists):
@@ -365,7 +355,7 @@ class ScenarioSweepResult:
 
 def scenario_sweep(named_workloads, hs=None,
                    ws=None, backend: str = "pallas", fused: bool = True,
-                   block_c: int = 128, cache_hit: float = 0.0,
+                   block_c: Optional[int] = None, cache_hit: float = 0.0,
                    spec_decode=None, **model_kw) -> ScenarioSweepResult:
     """Sweep the whole scenario matrix over the (h, w) grid.
 
@@ -429,17 +419,13 @@ def _scenario_sweep_body(named_workloads, names, hs, ws, H, W, shape,
             for k in _SWEEP_KEYS:
                 grids[k][i] = col[k].reshape(H.shape)
     elif backend == "pallas":
-        import jax.numpy as jnp
-
         from repro.kernels import ops
-        from repro.kernels.dse_eval import OUT_COLS, pad_configs
+        from repro.kernels.dse_eval import OUT_COLS
 
         layer_sets = pad_layer_sets([named_workloads[n] for n in names])
-        cfgs, C = pad_configs(
-            np.stack([H.reshape(-1), W.reshape(-1)], axis=1), block_c)
         out = np.asarray(ops.sweep_batched(
-            jnp.asarray(cfgs, jnp.float32), jnp.asarray(layer_sets),
-            block_c=block_c, **model_kw))[:, :C]
+            np.stack([H.reshape(-1), W.reshape(-1)], axis=1), layer_sets,
+            block_c=block_c, **model_kw))
         cols = {k: out[:, :, j] for j, k in enumerate(OUT_COLS)}
         cols["ub_bw_bits"] = cols.pop("ub_bandwidth_bits")
         grids = {k: cols[k].reshape(shape).astype(np.float64)

@@ -25,8 +25,8 @@ vectorized probe per bisection round:
     request buffers edited in place between rounds, retired lanes parked
     on trivial length-1 traces (XLA shapes are jit-static — shrinking the
     batch would recompile). The native C executor is preferred when a
-    compiler is present; the XLA lockstep engine and the scalar simulator
-    are fallbacks. All three produce identical numbers.
+    compiler is present; the XLA lockstep engine (off-TPU only) and the
+    scalar simulator are fallbacks. All three produce identical numbers.
 
 `nsga2_device` and `refine_design_point` move the other two search loops
 of the DSE onto the device: a fixed-shape NSGA-2 whose jnp generation
@@ -235,7 +235,8 @@ class _ServerBatch:
 
     Backend selection (`auto`): the runtime-compiled C replay
     (`traffic.native`) when a compiler is present and the config fits its
-    limits, else the XLA lockstep engine, else the scalar simulator.
+    limits, else the XLA lockstep engine where it is exact (not on a TPU
+    backend, where an explicit "xla" raises), else the scalar simulator.
     Every backend is bit-identical to `traffic.sim.simulate` per lane."""
 
     def __init__(self, tables: Sequence, cfg: SimConfig, n_max: int,
@@ -291,6 +292,9 @@ class _ServerBatch:
                 raise RuntimeError(
                     "native backend requested but unavailable "
                     "(no C compiler, or slots > 64)")
+        from repro.traffic.lockstep import exact_on_backend
+        if backend == "auto" and not exact_on_backend():
+            return "scalar"                # emulated f64 would drift
         return "xla"
 
     def run_round(self, jobs: Dict[int, RequestTrace]
@@ -645,7 +649,7 @@ def nsga2_device(eval_fn, bounds, *, pop: int = 64, gens: int = 40,
         import jax
         import jax.numpy as jnp
         from jax import lax
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         with enable_x64():
             jlo, jhi = jnp.asarray(lo), jnp.asarray(hi)
@@ -722,7 +726,7 @@ def refine_design_point(workloads, seed_point, *,
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from jax.experimental import enable_x64
+    from jax import enable_x64
 
     from repro.core import systolic
 

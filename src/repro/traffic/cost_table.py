@@ -372,26 +372,21 @@ def _eval_lattice(workload_lists, hw, backend, block_c, **model_kw):
     if backend == "pallas-loop":
         # one dse_eval dispatch per lattice point: the unfused baseline
         from repro.core.dse import _pallas_eval_configs
-        bc = block_c or min(128, C)
         out = {k: np.empty((len(workload_lists), C), np.float64)
                for k in ("cycles", "energy", "macs")}
         for i, wls in enumerate(workload_lists):
-            col = _pallas_eval_configs(wls, cfgs, block_c=bc, **model_kw)
+            col = _pallas_eval_configs(wls, cfgs, block_c=block_c,
+                                       **model_kw)
             for k in out:
                 out[k][i] = col[k]
         return out
     if backend == "pallas":
-        import jax.numpy as jnp
-
         from repro.core.dse import pad_layer_sets
         from repro.kernels import ops
-        from repro.kernels.dse_eval import OUT_COLS, pad_configs
-        layer_sets = pad_layer_sets(workload_lists)
-        bc = block_c or min(128, C)
-        padded, C0 = pad_configs(cfgs, bc)
+        from repro.kernels.dse_eval import OUT_COLS
         out = np.asarray(ops.sweep_batched(
-            jnp.asarray(padded, jnp.float32), jnp.asarray(layer_sets),
-            block_c=bc, **model_kw))[:, :C0]
+            cfgs, pad_layer_sets(workload_lists), block_c=block_c,
+            **model_kw))
         return {k: out[:, :, OUT_COLS.index(k)].astype(np.float64)
                 for k in ("cycles", "energy", "macs")}
     raise ValueError(

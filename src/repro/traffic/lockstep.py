@@ -57,6 +57,13 @@ IEEE-754 doubles reproducible through XLA:
     (Integer-valued f64 arithmetic below 2^53 — the slot keys — is
     exact under any compilation and needs no guard.)
 
+These disciplines assume the backend does IEEE-754 binary64 arithmetic.
+A TPU emulates float64: on a v5e most f64 products, sums and quotients
+differ from the host's in the last bits (up to 3e-14 relative), and a
+1200-request replay drifts by up to 4.8e-10 relative in TTFT. So the
+engine refuses to run on a TPU backend (`exact_on_backend`) rather than
+drift silently from the reference.
+
 The infinite-buffer default (`ub_kib=None`) compiles a specialized
 no-spill engine: the scalar path's spill terms are all exact `+ 0.0` on
 strictly positive quantities there, so eliding them preserves bits.
@@ -379,6 +386,13 @@ def _engine(slots: int, spill: bool, dims: Tuple[int, int, int]):
 
 # ----------------------------------------------------------- public API ----
 
+def exact_on_backend() -> bool:
+    """False on a TPU backend, whose emulated float64 breaks the
+    bit-identity contract (see the module docstring)."""
+    import jax
+    return jax.default_backend() != "tpu"
+
+
 class LockstepBatch:
     """A reusable lane batch over FIXED tables: pack the table-side
     statics once, then `run` many probe rounds that differ only in their
@@ -390,10 +404,15 @@ class LockstepBatch:
     def __init__(self, tables: Sequence[object], cfg: SimConfig,
                  n_max: int):
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         if cfg.policy != "prefill_first":
             raise ValueError("LockstepBatch supports prefill_first only")
+        if not exact_on_backend():
+            raise RuntimeError(
+                "the lockstep replay engine is not bit-identical to "
+                "traffic.sim.simulate on a TPU backend (float64 is "
+                "emulated there); use the 'scalar' or 'native' backend")
         self.tables = list(tables)
         self.cfg = cfg
         self.n_max = int(n_max)
@@ -422,7 +441,7 @@ class LockstepBatch:
         """`run` on pre-packed request arrays (see `_pack_traces`) — the
         bisection driver edits only the arrival third between rounds."""
         import jax.numpy as jnp
-        from jax.experimental import enable_x64
+        from jax import enable_x64
 
         eng = _engine(self.cfg.slots, self.spill, self.dims)
         with enable_x64():

@@ -5,16 +5,6 @@ import os
 import subprocess
 import sys
 
-import jax
-import pytest
-
-# The sharding/launch stack targets the jax.shard_map API (jax >= 0.6);
-# on older jax these tests fail at import time inside the subprocess. Skip
-# in-file so bare `pytest -x -q` passes without CI-side deselects.
-pytestmark = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="requires the jax.shard_map API (jax >= 0.6)")
-
 SRC = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "src"))
 
 

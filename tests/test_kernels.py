@@ -61,18 +61,49 @@ def test_swa_attention_bf16():
                                rtol=5e-2, atol=5e-2)
 
 
-def test_dse_eval_vs_float64_model():
+@pytest.mark.parametrize("net,n_cfgs", [("resnet152", 896),
+                                         ("densenet201", 961)])
+def test_dse_eval_vs_float64_model(net, n_cfgs):
+    """Both tables are longer than one layer chunk (156 and 201 rows), and
+    961 configs are not a multiple of the block: the padded tail and the
+    chunked reduction must both come back exact to f32 roundoff."""
     from repro.core.cnn_zoo import get_workloads
     from repro.core.dse import grid_axes
-    layers = np.asarray(get_workloads("resnet152"), np.float32)
+    layers = np.asarray(get_workloads(net), np.float32)
     hs = grid_axes()
     H, W = np.meshgrid(hs, hs, indexing="ij")
-    cfgs = np.stack([H.reshape(-1), W.reshape(-1)], 1)[:896]
+    cfgs = np.stack([H.reshape(-1), W.reshape(-1)], 1)[:n_cfgs]
     got = np.asarray(ops.sweep(jnp.asarray(cfgs, jnp.float32),
                                jnp.asarray(layers), interpret=True))
+    assert got.shape == (n_cfgs, 8)
     want = ref.dse_eval_ref(cfgs, layers)
     rel = np.abs(got - want) / (np.abs(want) + 1.0)
     assert rel.max() < 1e-5
+
+
+@pytest.mark.parametrize("env_dir", [None, "/elsewhere/cache"])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    """`use_compile_cache` leaves a set JAX_COMPILATION_CACHE_DIR to JAX
+    and otherwise points the cache at the fixed in-checkout `.jax_cache`;
+    importing the kernels sets nothing."""
+    import os
+    prev = jax.config.jax_compilation_cache_dir
+    assert prev in (None, os.environ.get("JAX_COMPILATION_CACHE_DIR"))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    try:
+        got = ops.use_compile_cache()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        if env_dir is None:
+            assert got == os.path.join(repo, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+        else:
+            assert got == env_dir
+            assert jax.config.jax_compilation_cache_dir == prev
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
 
 
 def test_autotune_feasible_and_sane():

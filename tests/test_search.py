@@ -116,6 +116,35 @@ def test_batched_capacity_bit_identical(arrival):
             _summaries_equal(s0, s1)
 
 
+@pytest.mark.parametrize("backend", ["auto", "xla"])
+def test_tpu_backend_never_picks_lockstep(monkeypatch, backend):
+    """On a TPU backend the x64 lockstep engine is not bit-identical to
+    the scalar reference (emulated float64): `auto` without the native
+    executor takes the scalar path, and an explicit "xla" raises."""
+    import jax
+
+    from repro.traffic import native
+    ts = _tables()
+    tables = [ts.table(a, h, w) for a in ARCHS for h, w in HW]
+    tm = TrafficModel()
+    slo = SLO(ttft_s=2.0, tpot_s=0.1)
+    monkeypatch.setattr(native, "available", lambda: False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if backend == "xla":
+        with pytest.raises(RuntimeError, match="not bit-identical"):
+            batched_max_sustainable_qps(tables, [tm] * len(tables), slo,
+                                        n_requests=200, backend=backend)
+        return
+    stats = {}
+    bat = batched_max_sustainable_qps(tables, [tm] * len(tables), slo,
+                                      n_requests=200, backend=backend,
+                                      stats=stats)
+    assert stats["backend"] == "scalar"
+    seq = [max_sustainable_qps(t, tm, slo, n_requests=200, seed=0)
+           for t in tables]
+    assert [q for q, _ in bat] == [q for q, _ in seq]
+
+
 def test_slo_sweep_batched_equals_sequential():
     tm = TrafficModel()
     slo = SLO(ttft_s=2.0, tpot_s=0.1)

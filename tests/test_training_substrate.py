@@ -29,10 +29,6 @@ def _tiny_setup(tmp, arch="yi-9b", accum=1):
     return b, state, step, data, cfg
 
 
-@pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="seed-known-failing on jax without the jax.shard_map API "
-           "(pre-0.6 pins; see CHANGES.md)")
 def test_loss_decreases(tmp_path):
     _, state, step, data, _ = _tiny_setup(tmp_path)
     losses = []
