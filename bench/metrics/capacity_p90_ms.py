@@ -1,0 +1,6 @@
+"""90th percentile of the capacity queries' latencies, in ms."""
+import numpy as np
+
+
+def read(run):
+    return 1e3 * float(np.percentile(run.durations, 90))
