@@ -102,15 +102,22 @@ def _pallas_eval_configs(workloads, cfgs, block_c=None, **model_kw):
     kernel, returning a dict of per-config metric columns.
 
     `kernels.ops` pads the config list to the kernel block and slices the
-    result back; off-TPU the kernel runs in interpret mode.
+    result back; off-TPU the kernel runs in interpret mode. Traces
+    `sweep.put` (the layer table), `sweep.fetch` (waiting for the device
+    and the copy back) and `sweep.assemble` (the columns).
     """
     from repro.kernels import ops
     from repro.kernels.dse_eval import OUT_COLS
 
-    layers = np.asarray(
-        [(m, k, n, g, r) for (m, k, n, g, r) in workloads], np.float32)
-    out = np.asarray(ops.sweep(cfgs, layers, block_c=block_c, **model_kw))
-    return {k: out[:, j] for j, k in enumerate(OUT_COLS)}
+    tr = _obs_tracer()
+    with tr.span("sweep.put", "dse"):
+        layers = np.asarray(
+            [(m, k, n, g, r) for (m, k, n, g, r) in workloads], np.float32)
+    out = ops.sweep(cfgs, layers, block_c=block_c, **model_kw)
+    with tr.span("sweep.fetch", "dse"):
+        out = np.asarray(out)
+    with tr.span("sweep.assemble", "dse"):
+        return {k: out[:, j] for j, k in enumerate(OUT_COLS)}
 
 
 def _grid_sweep_pallas(workloads, hs, ws, H, W, block_c=None, **model_kw):
@@ -422,14 +429,19 @@ def _scenario_sweep_body(named_workloads, names, hs, ws, H, W, shape,
         from repro.kernels import ops
         from repro.kernels.dse_eval import OUT_COLS
 
-        layer_sets = pad_layer_sets([named_workloads[n] for n in names])
-        out = np.asarray(ops.sweep_batched(
-            np.stack([H.reshape(-1), W.reshape(-1)], axis=1), layer_sets,
-            block_c=block_c, **model_kw))
-        cols = {k: out[:, :, j] for j, k in enumerate(OUT_COLS)}
-        cols["ub_bw_bits"] = cols.pop("ub_bandwidth_bits")
-        grids = {k: cols[k].reshape(shape).astype(np.float64)
-                 for k in _SWEEP_KEYS}
+        tr = _obs_tracer()
+        with tr.span("sweep.put", "dse"):
+            layer_sets = pad_layer_sets([named_workloads[n] for n in names])
+            cfgs = np.stack([H.reshape(-1), W.reshape(-1)], axis=1)
+        out = ops.sweep_batched(cfgs, layer_sets, block_c=block_c,
+                                **model_kw)
+        with tr.span("sweep.fetch", "dse"):
+            out = np.asarray(out)
+        with tr.span("sweep.assemble", "dse"):
+            cols = {k: out[:, :, j] for j, k in enumerate(OUT_COLS)}
+            cols["ub_bw_bits"] = cols.pop("ub_bandwidth_bits")
+            grids = {k: cols[k].reshape(shape).astype(np.float64)
+                     for k in _SWEEP_KEYS}
     else:
         raise ValueError(f"unknown backend {backend!r} (numpy|pallas)")
 
@@ -579,6 +591,9 @@ class SLOSweepResult:
     energy_per_token: np.ndarray    # (A, C)
     goodput_qps: np.ndarray         # (A, C)
     summaries: List[List[dict]]
+    # the batched search's `backend` (replay engine), `rounds`, `probes`
+    # and `lanes`; None after a sequential search
+    search_stats: Optional[Dict] = None
 
     def best(self, arch: str):
         """(h, w, max_qps) of the highest-capacity config for one arch."""
@@ -728,6 +743,7 @@ def slo_capacity_sweep(traffic, slo, archs: Optional[Sequence[str]] = None,
     ept = np.zeros((A, C))
     good = np.zeros((A, C))
     summaries: List[List[dict]] = []
+    stats = None
     with _tr.span("capacity_search", "dse", search=search, lanes=A * C):
         if search == "sequential":
             points = [
@@ -737,10 +753,11 @@ def slo_capacity_sweep(traffic, slo, archs: Optional[Sequence[str]] = None,
                                      seed=seed) for h, w in hw]
                 for arch in archs]
         else:
+            stats = {}
             flat = batched_max_sustainable_qps(
                 [tables.table(arch, h, w) for arch in archs for h, w in hw],
                 [per_arch[arch] for arch in archs for _ in hw],
-                slo, sim=sim, n_requests=n_requests, seed=seed)
+                slo, sim=sim, n_requests=n_requests, seed=seed, stats=stats)
             points = [flat[a * C:(a + 1) * C] for a in range(A)]
     for a in range(A):
         row = []
@@ -766,7 +783,8 @@ def slo_capacity_sweep(traffic, slo, archs: Optional[Sequence[str]] = None,
             _annotate_windowed(qps, summaries, wcfg, monitor, replay)
     return SLOSweepResult(archs=archs, hw=np.asarray(hw, np.int64),
                           slo=slo, max_qps=qps, energy_per_token=ept,
-                          goodput_qps=good, summaries=summaries)
+                          goodput_qps=good, summaries=summaries,
+                          search_stats=stats)
 
 
 def _robust_mix_frontier(archs, max_qps, energy_per_token,

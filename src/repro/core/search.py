@@ -299,33 +299,46 @@ class _ServerBatch:
 
     def run_round(self, jobs: Dict[int, RequestTrace]
                   ) -> Dict[int, SimResult]:
+        """Replay one round (span `search.replay`: packing and the packed
+        engine, or the scalar simulator) and, on a packed engine, build
+        the per-lane results (span `search.score`). A packed engine
+        counts the round's requests in `sim.requests`, as the scalar
+        simulator does per replay (but no `sim.replays`)."""
         t0 = time.perf_counter()
+        tr_obs = _obs_tracer()
         if self.backend == "scalar":
-            return {i: simulate(self.tables[i], tr, self.cfg)
-                    for i, tr in jobs.items()}
+            with tr_obs.span("search.replay", "bisect"):
+                return {i: simulate(self.tables[i], tr, self.cfg)
+                        for i, tr in jobs.items()}
         req, n = self._req, self._n
-        for i in self._dirty - jobs.keys():  # park lanes that just retired
-            req[i, 0, :] = np.inf
-            req[i, 0, 0] = 0.0
-            n[i] = 1
-        self._dirty = set(jobs)
-        for i, tr in jobs.items():
-            k = len(tr)
-            n[i] = k
-            req[i, 0, :k] = tr.arrival_s
-            req[i, 0, k:] = np.inf
-            req[i, 1, :k] = tr.prompt_len
-            req[i, 1, k:] = 1.0
-            req[i, 2, :k] = tr.output_len
-            req[i, 2, k:] = 1.0
-        if self.backend == "native":
-            res = self._batch.run_packed(req, n)
-        else:
-            res = self._batch.run_packed(req.reshape(req.shape[0], -1), n)
+        with tr_obs.span("search.replay", "bisect"):
+            for i in self._dirty - jobs.keys():  # park lanes just retired
+                req[i, 0, :] = np.inf
+                req[i, 0, 0] = 0.0
+                n[i] = 1
+            self._dirty = set(jobs)
+            for i, tr in jobs.items():
+                k = len(tr)
+                n[i] = k
+                req[i, 0, :k] = tr.arrival_s
+                req[i, 0, k:] = np.inf
+                req[i, 1, :k] = tr.prompt_len
+                req[i, 1, k:] = 1.0
+                req[i, 2, :k] = tr.output_len
+                req[i, 2, k:] = 1.0
+            if self.backend == "native":
+                res = self._batch.run_packed(req, n)
+            else:
+                res = self._batch.run_packed(req.reshape(req.shape[0], -1),
+                                             n)
         wall = time.perf_counter() - t0
+        _obs_metrics().add_many(
+            {"sim.requests": sum(len(tr) for tr in jobs.values())})
         from repro.traffic.lockstep import _to_result
-        return {i: _to_result(self.tables[i], tr, self.cfg, res, i, wall)
-                for i, tr in jobs.items()}
+        with tr_obs.span("search.score", "bisect"):
+            return {i: _to_result(self.tables[i], tr, self.cfg, res, i,
+                                  wall)
+                    for i, tr in jobs.items()}
 
 
 # ------------------------------------------- batched capacity searches ------
@@ -346,14 +359,17 @@ def batched_max_sustainable_qps(
     ex = _ServerBatch(tables, sim, n_requests, backend=backend)
     tf = _TraceFactory()
     n_probes = 0
+    tr = _obs_tracer()
 
     def probe_batch(reqs):
         nonlocal n_probes
         n_probes += len(reqs)
-        jobs = {i: tf.trace(traffics[i], q, n_requests, seed, False)
-                for i, q in reqs}
+        with tr.span("search.sample", "bisect"):
+            jobs = {i: tf.trace(traffics[i], q, n_requests, seed, False)
+                    for i, q in reqs}
         res = ex.run_round(jobs)
-        return [(meets_slo(res[i], slo), res[i]) for i, _ in reqs]
+        with tr.span("search.score", "bisect"):
+            return [(meets_slo(res[i], slo), res[i]) for i, _ in reqs]
 
     brackets = [2.0 * saturation_qps(t, tm, sim)
                 for t, tm in zip(tables, traffics)]

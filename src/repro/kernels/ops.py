@@ -17,6 +17,7 @@ from repro.kernels.dse_eval import BLOCK_C, dse_eval, dse_eval_batched
 from repro.kernels.swa_attention import swa_attention
 from repro.kernels.ws_matmul import ws_matmul
 from repro.obs.metrics import metrics as _obs_metrics
+from repro.obs.trace import tracer as _obs_tracer
 
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))))
@@ -81,12 +82,17 @@ def sweep(configs, layers, *, block_c=None, interpret=None, **model_kw):
 
     Counts one `kernels.sweep_dispatches` per call — here in the plain
     wrapper, NOT inside the jitted `dse_eval` (which only runs its Python
-    body at trace time), so the counter reflects actual dispatches."""
+    body at trace time), so the counter reflects actual dispatches.
+    Traces `sweep.put` (padding and the copy to the device) and
+    `sweep.enqueue` (the kernel call and the slice, both asynchronous)."""
     _obs_metrics().inc("kernels.sweep_dispatches")
     interpret = _default_interpret() if interpret is None else interpret
-    padded, C, block_c = _pad(configs, block_c)
-    return dse_eval(padded, layers, block_c=block_c, interpret=interpret,
-                    **model_kw)[:C]
+    tr = _obs_tracer()
+    with tr.span("sweep.put", "dse"):
+        padded, C, block_c = _pad(configs, block_c)
+    with tr.span("sweep.enqueue", "dse"):
+        return dse_eval(padded, layers, block_c=block_c,
+                        interpret=interpret, **model_kw)[:C]
 
 
 def sweep_batched(configs, layer_sets, *, block_c=None, interpret=None,
@@ -97,9 +103,13 @@ def sweep_batched(configs, layer_sets, *, block_c=None, interpret=None,
 
     Counts one `kernels.fused_dispatches` per call (in the wrapper, not
     the jitted body) — the counter the "ONE fused dispatch per sweep"
-    regression tests assert on."""
+    regression tests assert on. Traces `sweep.put` and `sweep.enqueue`
+    as `sweep` does."""
     _obs_metrics().inc("kernels.fused_dispatches")
     interpret = _default_interpret() if interpret is None else interpret
-    padded, C, block_c = _pad(configs, block_c)
-    return dse_eval_batched(padded, layer_sets, block_c=block_c,
-                            interpret=interpret, **model_kw)[:, :C]
+    tr = _obs_tracer()
+    with tr.span("sweep.put", "dse"):
+        padded, C, block_c = _pad(configs, block_c)
+    with tr.span("sweep.enqueue", "dse"):
+        return dse_eval_batched(padded, layer_sets, block_c=block_c,
+                                interpret=interpret, **model_kw)[:, :C]

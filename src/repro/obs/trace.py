@@ -6,8 +6,12 @@ samples — in ONE clock domain:
 
   * ``clock="wall"`` — host time (`time.perf_counter` relative to the
     tracer's birth); timestamps default to "now". The DSE drivers
-    (`core.dse`, `core.search`) trace their sweep stages and lockstep
-    rounds on this clock.
+    (`core.dse`, `core.search`), the sweep kernel's wrappers
+    (`kernels.ops`) and the cost-table build trace their stages and
+    lockstep rounds on this clock. An enabled wall-clock tracer also
+    opens ``jax.profiler.TraceAnnotation("repro." + name)`` for every
+    span, so under a JAX profiler session the spans land in its
+    `.xplane.pb` on the same clock as the device's operations.
   * ``clock="sim"``  — simulated time; every event MUST carry an explicit
     timestamp (the simulation clock is the caller's, not the host's).
     `traffic.sim` / `fleet.sim` emit per-request lifecycle events here,
@@ -50,6 +54,23 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+# profiler annotations carry this prefix, to tell them from other host
+# events in a JAX profiler trace
+PROFILER_PREFIX = "repro."
+_ANNOTATION = None
+
+
+def _annotation(name: str):
+    """Open a JAX profiler annotation for span `name` (jax imported on
+    first use, so the tracer stays free of it until a wall span opens).
+    Without a profiler session running it records nothing."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation as _ANNOTATION
+    ann = _ANNOTATION(PROFILER_PREFIX + name)
+    ann.__enter__()
+    return ann
+
 
 class _Span:
     __slots__ = ("_tr", "_name", "_track", "_args")
@@ -77,7 +98,7 @@ class Tracer:
     closed before disabling, or the trace will report unbalanced spans).
     """
 
-    __slots__ = ("enabled", "clock", "events", "_stacks", "_t0")
+    __slots__ = ("enabled", "clock", "events", "_stacks", "_anns", "_t0")
 
     def __init__(self, enabled: bool = True, clock: str = "wall"):
         if clock not in CLOCKS:
@@ -86,6 +107,7 @@ class Tracer:
         self.clock = clock
         self.events: List[Tuple] = []
         self._stacks = {}               # track -> [span names] (B/E pairing)
+        self._anns = {}                 # track -> [open profiler annotations]
         self._t0 = time.perf_counter()
 
     # ------------------------------------------------------------- clock --
@@ -103,10 +125,13 @@ class Tracer:
     # ---------------------------------------------------------- emission --
     def begin(self, name: str, track: str = "main",
               ts: Optional[float] = None, **args) -> None:
-        """Open a nested span on `track` (Chrome 'B')."""
+        """Open a nested span on `track` (Chrome 'B'); on the wall clock
+        also a profiler annotation, closed by the matching `end`."""
         if not self.enabled:
             return
         self._stacks.setdefault(track, []).append(name)
+        if self.clock == "wall":
+            self._anns.setdefault(track, []).append(_annotation(name))
         self.events.append(("B", name, track, self._ts(ts), None, None,
                             args or None))
 
@@ -119,6 +144,8 @@ class Tracer:
         if not stack:
             raise RuntimeError(f"end() with no open span on {track!r}")
         name = stack.pop()
+        if self.clock == "wall":
+            self._anns[track].pop().__exit__(None, None, None)
         self.events.append(("E", name, track, self._ts(ts), None, None,
                             args or None))
 
@@ -194,6 +221,7 @@ class Tracer:
     def clear(self) -> None:
         self.events.clear()
         self._stacks.clear()
+        self._anns.clear()
 
     def __len__(self) -> int:
         return len(self.events)

@@ -32,6 +32,7 @@ import numpy as np
 
 from repro.configs.base import ShapeConfig, get_config, list_archs
 from repro.core.lm_workloads import extract_workloads
+from repro.obs.trace import tracer as _obs_tracer
 
 # Default design points for capacity planning: square sizes spanning the
 # paper's grid plus the tall/wide aspect extremes that Fig. 6 shows can
@@ -268,6 +269,9 @@ def build_cost_tables(archs: Optional[Sequence[str]] = None,
     lattices, same (h, w) array) and the target arch's verify grid at
     batch `slot * (k + 1)`. The default `spec=None` adds no lattice
     point and produces byte-identical tables.
+
+    Traces `tables.lower` (the lattice lowering) and `tables.assemble`
+    (the `CostTable`s from the kernel's columns).
     """
     import time
 
@@ -282,72 +286,75 @@ def build_cost_tables(archs: Optional[Sequence[str]] = None,
         draft_cfg = get_config(spec.draft_arch)
         per_arch += 2 * nb * nk
 
-    workload_lists, metas = [], []
-    for arch in archs:
-        cfg = get_config(arch)
-        for shape in _lattice_shapes(slot_lattice, kv_lattice,
-                                     prompt_lattice):
-            workload_lists.append(extract_workloads(cfg, shape))
-        if spec is not None:
-            # draft-model steps: the draft arch's decode lattice
-            for b in slot_lattice:
-                for s in kv_lattice:
-                    workload_lists.append(extract_workloads(
-                        draft_cfg,
-                        ShapeConfig(f"sd{b}x{s}", int(s), int(b),
-                                    "decode")))
-            # verify batches: each of the k+1 speculated positions is a
-            # GEMM row, so one verify step is decode at batch b*(k+1)
-            for b in slot_lattice:
-                for s in kv_lattice:
-                    workload_lists.append(extract_workloads(
-                        cfg,
-                        ShapeConfig(f"sv{b}x{s}", int(s),
-                                    int(b) * (spec.k + 1), "decode")))
-        metas.append((arch, kv_bits_per_token(cfg, act_bits)))
+    tr = _obs_tracer()
+    with tr.span("tables.lower", "dse"):
+        workload_lists, metas = [], []
+        for arch in archs:
+            cfg = get_config(arch)
+            for shape in _lattice_shapes(slot_lattice, kv_lattice,
+                                         prompt_lattice):
+                workload_lists.append(extract_workloads(cfg, shape))
+            if spec is not None:
+                # draft-model steps: the draft arch's decode lattice
+                for b in slot_lattice:
+                    for s in kv_lattice:
+                        workload_lists.append(extract_workloads(
+                            draft_cfg,
+                            ShapeConfig(f"sd{b}x{s}", int(s), int(b),
+                                        "decode")))
+                # verify batches: each of the k+1 speculated positions is a
+                # GEMM row, so one verify step is decode at batch b*(k+1)
+                for b in slot_lattice:
+                    for s in kv_lattice:
+                        workload_lists.append(extract_workloads(
+                            cfg,
+                            ShapeConfig(f"sv{b}x{s}", int(s),
+                                        int(b) * (spec.k + 1), "decode")))
+            metas.append((arch, kv_bits_per_token(cfg, act_bits)))
 
     t0 = time.perf_counter()
     cols = _eval_lattice(workload_lists, hw, backend, block_c, **model_kw)
     build_s = time.perf_counter() - t0
 
-    # cols: (S, C) arrays for cycles / energy / macs
-    tables: Dict[Tuple[str, int, int], CostTable] = {}
-    for a, (arch, kvb) in enumerate(metas):
-        base = a * per_arch
-        dec = slice(base, base + nb * nk)
-        pre = slice(base + nb * nk, base + nb * nk + npr)
-        for c, (h, w) in enumerate(hw):
-            dc = cols["cycles"][dec, c].reshape(nb, nk)
-            de = cols["energy"][dec, c].reshape(nb, nk)
-            dm = cols["macs"][dec, c].reshape(nb, nk)
-            spec_kw = {}
-            if spec is not None:
-                sd = slice(base + nb * nk + npr,
-                           base + nb * nk + npr + nb * nk)
-                sv = slice(base + nb * nk + npr + nb * nk, base + per_arch)
-                spec_kw = dict(
-                    spec_k=int(spec.k), draft_arch=spec.draft_arch,
-                    draft_cycles=cols["cycles"][sd, c]
-                    .reshape(nb, nk).tolist(),
-                    draft_energy=cols["energy"][sd, c]
-                    .reshape(nb, nk).tolist(),
-                    draft_macs=cols["macs"][sd, c]
-                    .reshape(nb, nk).tolist(),
-                    verify_cycles=cols["cycles"][sv, c]
-                    .reshape(nb, nk).tolist(),
-                    verify_energy=cols["energy"][sv, c]
-                    .reshape(nb, nk).tolist(),
-                    verify_macs=cols["macs"][sv, c]
-                    .reshape(nb, nk).tolist())
-            tables[(arch, h, w)] = CostTable(
-                arch=arch, h=h, w=w,
-                slot_lattice=slot_l, kv_lattice=kv_l,
-                prompt_lattice=prompt_l,
-                decode_cycles=dc.tolist(), decode_energy=de.tolist(),
-                decode_macs=dm.tolist(),
-                prefill_cycles=cols["cycles"][pre, c].tolist(),
-                prefill_energy=cols["energy"][pre, c].tolist(),
-                kv_bits_per_token=kvb, pe=float(h * w), **spec_kw)
+    with tr.span("tables.assemble", "dse"):
+        # cols: (S, C) arrays for cycles / energy / macs
+        tables: Dict[Tuple[str, int, int], CostTable] = {}
+        for a, (arch, kvb) in enumerate(metas):
+            base = a * per_arch
+            dec = slice(base, base + nb * nk)
+            pre = slice(base + nb * nk, base + nb * nk + npr)
+            for c, (h, w) in enumerate(hw):
+                dc = cols["cycles"][dec, c].reshape(nb, nk)
+                de = cols["energy"][dec, c].reshape(nb, nk)
+                dm = cols["macs"][dec, c].reshape(nb, nk)
+                spec_kw = {}
+                if spec is not None:
+                    sd = slice(base + nb * nk + npr,
+                               base + nb * nk + npr + nb * nk)
+                    sv = slice(base + nb * nk + npr + nb * nk, base + per_arch)
+                    spec_kw = dict(
+                        spec_k=int(spec.k), draft_arch=spec.draft_arch,
+                        draft_cycles=cols["cycles"][sd, c]
+                        .reshape(nb, nk).tolist(),
+                        draft_energy=cols["energy"][sd, c]
+                        .reshape(nb, nk).tolist(),
+                        draft_macs=cols["macs"][sd, c]
+                        .reshape(nb, nk).tolist(),
+                        verify_cycles=cols["cycles"][sv, c]
+                        .reshape(nb, nk).tolist(),
+                        verify_energy=cols["energy"][sv, c]
+                        .reshape(nb, nk).tolist(),
+                        verify_macs=cols["macs"][sv, c]
+                        .reshape(nb, nk).tolist())
+                tables[(arch, h, w)] = CostTable(
+                    arch=arch, h=h, w=w,
+                    slot_lattice=slot_l, kv_lattice=kv_l,
+                    prompt_lattice=prompt_l,
+                    decode_cycles=dc.tolist(), decode_energy=de.tolist(),
+                    decode_macs=dm.tolist(),
+                    prefill_cycles=cols["cycles"][pre, c].tolist(),
+                    prefill_energy=cols["energy"][pre, c].tolist(),
+                    kv_bits_per_token=kvb, pe=float(h * w), **spec_kw)
     return CostTableSet(tables=tables, archs=archs, hw=hw,
                         n_scenarios=len(workload_lists), n_configs=len(hw),
                         backend=backend, build_seconds=build_s)
@@ -384,10 +391,15 @@ def _eval_lattice(workload_lists, hw, backend, block_c, **model_kw):
         from repro.core.dse import pad_layer_sets
         from repro.kernels import ops
         from repro.kernels.dse_eval import OUT_COLS
-        out = np.asarray(ops.sweep_batched(
-            cfgs, pad_layer_sets(workload_lists), block_c=block_c,
-            **model_kw))
-        return {k: out[:, :, OUT_COLS.index(k)].astype(np.float64)
-                for k in ("cycles", "energy", "macs")}
+        tr = _obs_tracer()
+        with tr.span("sweep.put", "dse"):
+            layer_sets = pad_layer_sets(workload_lists)
+        out = ops.sweep_batched(cfgs, layer_sets, block_c=block_c,
+                                **model_kw)
+        with tr.span("sweep.fetch", "dse"):
+            out = np.asarray(out)
+        with tr.span("sweep.assemble", "dse"):
+            return {k: out[:, :, OUT_COLS.index(k)].astype(np.float64)
+                    for k in ("cycles", "energy", "macs")}
     raise ValueError(
         f"unknown backend {backend!r} (numpy|pallas|pallas-loop)")

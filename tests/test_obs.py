@@ -298,3 +298,111 @@ def test_dse_sweep_emits_wall_spans():
         assert obs.validate_trace(obs.to_trace_events(tr)) == []
     finally:
         obs.set_tracer(old)
+
+
+# ----------------------------------------- wall spans in the profiler trace --
+
+def _profiler_spans(log_dir):
+    """(name, start_ns, end_ns) of every `repro.` host event in the newest
+    JAX profiler trace under `log_dir`, in order of start."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(os.path.join(
+        str(log_dir), "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    return sorted(((e.name, int(e.start_ns),
+                    int(e.start_ns) + int(e.duration_ns))
+                   for plane in ProfileData.from_file(path).planes
+                   for line in plane.lines for e in line.events
+                   if e.name.startswith(obs.trace.PROFILER_PREFIX)),
+                  key=lambda s: s[1])
+
+
+def _inside(inner, outer):
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+def _profiled(log_dir, tracing, fn):
+    """Run `fn` under a JAX profiler session with the process tracer on
+    (`tracing`) or off; returns the tracer it ran with."""
+    import jax
+    old = obs.set_tracer(obs.Tracer(enabled=False, clock="wall"))
+    try:
+        with jax.profiler.trace(str(log_dir)):
+            tr = obs.enable_tracing() if tracing else obs.tracer()
+            try:
+                fn()
+            finally:
+                obs.disable_tracing()
+        return tr
+    finally:
+        obs.set_tracer(old)
+
+
+def _tiny_sweep():
+    from repro.core import get_workloads
+    from repro.core.dse import grid_sweep
+    axis = np.array([64, 160, 256])
+    grid_sweep(get_workloads("alexnet"), hs=axis, ws=axis, backend="pallas")
+
+
+def test_wall_spans_land_in_the_profiler_trace(tmp_path):
+    from repro.core.dse import slo_capacity_sweep
+
+    def work():
+        _tiny_sweep()
+        slo_capacity_sweep(TrafficModel(rate_qps=10.0, prompt_median=64,
+                                        output_median=8),
+                           SLO(ttft_s=5.0, tpot_s=1.0), archs=[ARCH],
+                           hw=((64, 64),), tables=_tables(),
+                           sim=SimConfig(slots=4), n_requests=60, seed=0)
+
+    tr = _profiled(tmp_path, True, work)
+    spans = _profiler_spans(tmp_path)
+    # one profiler event per span the tracer recorded, under its name
+    begun = [ev[obs.trace.NAME] for ev in tr.events if ev[obs.trace.PH] == "B"]
+    assert sorted(n[len("repro."):] for n, _, _ in spans) == sorted(begun)
+    # the sweep: layer table, padding, dispatch, copy back, columns, each
+    # closed before the next opens
+    sweep = [s for s in spans if s[0].startswith("repro.sweep.")]
+    assert [s[0] for s in sweep] == [
+        "repro.sweep.put", "repro.sweep.put", "repro.sweep.enqueue",
+        "repro.sweep.fetch", "repro.sweep.assemble"]
+    assert all(a[2] <= b[1] for a, b in zip(sweep, sweep[1:]))
+    # the search: every step inside a lockstep round, every round inside
+    # the capacity search
+    (search,) = [s for s in spans if s[0] == "repro.capacity_search"]
+    rounds = [s for s in spans if s[0] == "repro.lockstep_round"]
+    steps = [s for s in spans if s[0].startswith("repro.search.")]
+    assert rounds and all(_inside(r, search) for r in rounds)
+    assert {s[0] for s in steps} == {"repro.search.sample",
+                                     "repro.search.replay",
+                                     "repro.search.score"}
+    assert all(any(_inside(s, r) for r in rounds) for s in steps)
+
+
+def test_disabled_tracing_writes_no_profiler_spans(tmp_path):
+    tr = _profiled(tmp_path, False, _tiny_sweep)
+    assert not tr.enabled and len(tr) == 0
+    assert tr.span("sweep.put", "dse") is obs.trace._NULL_SPAN
+    assert _profiler_spans(tmp_path) == []
+
+
+def test_packed_search_counts_requests_and_reports_its_engine():
+    from repro.core.dse import slo_capacity_sweep
+    before = obs.metrics().snapshot()
+    res = slo_capacity_sweep(TrafficModel(rate_qps=10.0, prompt_median=64,
+                                          output_median=8),
+                             SLO(ttft_s=5.0, tpot_s=1.0), archs=[ARCH],
+                             hw=((64, 64), (128, 128)), tables=_tables(),
+                             sim=SimConfig(slots=4), n_requests=60, seed=0)
+    d = obs.metrics().delta(before)
+    stats = res.search_stats
+    assert stats["backend"] in ("native", "xla")
+    assert stats["probes"] == d["search.probes"] > 0
+    assert stats["rounds"] == d["search.lockstep_rounds"]
+    # the packed engines count requests, not replays (a replay counted
+    # would read as the scalar engine)
+    assert d["sim.requests"] == 60 * d["search.probes"]
+    assert d.get("sim.replays", 0) == 0
