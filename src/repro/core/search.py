@@ -19,8 +19,9 @@ vectorized probe per bisection round:
     rates reuse one cached set of exponential/length draws and rebuild
     only the arrival cumsum (draw-for-draw what
     `TrafficModel.with_rate(q).sample(n, seed)` produces). Arrival
-    processes that consume rate-dependent entropy (mmpp) fall back to the
-    full sampler per probe.
+    processes that consume rate-dependent entropy (mmpp) take the full
+    sampler per probe; for mmpp its sojourn loop runs in C on the
+    Generator's own bit stream (`traffic.native`).
   * `_ServerBatch` owns the packed lane engine: fixed tables, persistent
     request buffers edited in place between rounds, retired lanes parked
     on trivial length-1 traces (XLA shapes are jit-static — shrinking the
@@ -187,8 +188,13 @@ class _TraceFactory:
     so probes at different rates reuse one cached draw and rebuild only
     the arrival cumsum — bitwise what
     `TrafficModel.with_rate(q).sample(n, seed, paired=...)` returns.
-    Arrival processes that consume rate-dependent entropy (mmpp) and
-    recorded traces fall back to the full sampler every probe."""
+    Arrival processes that consume rate-dependent entropy and recorded
+    traces take the full sampler every probe: `rng.poisson(rate * dwell)`
+    consumes a rate-dependent number of draws, so no mmpp draw carries
+    over between rates. The mmpp sampler's sojourn loop runs in C where
+    `traffic.native.mmpp_sampler` built (same bytes, draw for draw), so
+    the bisection's low first rates, whose traces span tens of thousands
+    of sojourns, no longer pay a Python iteration per sojourn."""
 
     def __init__(self):
         self._cache: Dict = {}

@@ -30,6 +30,8 @@ Counter catalog (the names the stack emits; see README "Observability"):
     slo.bisection_probes       scalar capacity-search probe replays
     search.lockstep_rounds     batched-bisection rounds (one packed replay)
     search.probes              lane-probes served by those rounds
+    sample.mmpp_native         MMPP traces drawn by the compiled sampler
+    sample.mmpp_fallback       MMPP traces drawn by the Python loop
 """
 from __future__ import annotations
 

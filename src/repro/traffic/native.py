@@ -1,4 +1,4 @@
-"""Optional cc-compiled lane executor for the batched capacity search.
+"""Optional cc-compiled replay executor and MMPP sampler for the search.
 
 `core.search` replays hundreds of (design point, probe) lanes per sweep.
 The XLA lockstep engine (`traffic.lockstep`) amortizes Python dispatch
@@ -23,6 +23,16 @@ Everything degrades gracefully: if no C compiler is present or the
 compile fails, `available()` returns False and callers fall back to the
 XLA or scalar paths. The shared object is cached under the system temp
 directory keyed by source hash.
+
+The module also builds, as a separate shared object, the MMPP arrival
+sampler behind `workload.mmpp_arrivals`: the sojourn loop in C, linked
+against the installed numpy's `libnpyrandom.a` and run on the caller's
+Generator through `bit_generator.ctypes`, so it calls numpy's own
+exponential and poisson and draws its uniforms as `Generator.uniform`
+does, in the Python loop's order. Its cache key also hashes
+`numpy.__version__` and the static library's bytes, so a numpy upgrade
+rebuilds it; if it does not build, `mmpp_sampler()` returns None and the
+Python loop runs, and the replay engine is unaffected.
 """
 from __future__ import annotations
 
@@ -223,21 +233,27 @@ _lib: Optional[object] = None
 _tried = False
 
 
-def _compile() -> Optional[object]:
-    """Build (or reuse) the shared object; None on any failure."""
-    tag = hashlib.sha256(_C_SOURCE.encode()).hexdigest()[:16]
+def _build(source: str, name: str, flags: Sequence[str] = (),
+           key: bytes = b"") -> Optional[str]:
+    """Compile `source` into a shared object (or reuse the cached one) and
+    return its path; None when no compiler builds it. The cache name
+    hashes the source, the flags and `key` (whatever else the object
+    depends on)."""
+    h = hashlib.sha256(source.encode())
+    h.update("\0".join(flags).encode())
+    h.update(key)
     cache = os.path.join(tempfile.gettempdir(),
-                         f"repro_native_{tag}.so")
+                         f"{name}_{h.hexdigest()[:16]}.so")
     if not os.path.exists(cache):
         src = cache[:-3] + ".c"
         with open(src, "w") as f:
-            f.write(_C_SOURCE)
+            f.write(source)
         tmp = cache + f".tmp{os.getpid()}"
         for cc in ("cc", "gcc", "clang"):
             try:
                 r = subprocess.run(
-                    [cc, "-O2", "-fPIC", "-shared",
-                     "-ffp-contract=off", "-o", tmp, src],
+                    [cc, "-O2", "-fPIC", "-shared", "-ffp-contract=off",
+                     "-o", tmp, src, *flags],
                     capture_output=True, timeout=120)
             except (OSError, subprocess.TimeoutExpired):
                 continue
@@ -246,7 +262,15 @@ def _compile() -> Optional[object]:
                 break
         else:
             return None
-    lib = ctypes.CDLL(cache)
+    return cache
+
+
+def _compile() -> Optional[object]:
+    """Build (or reuse) the replay shared object; None on any failure."""
+    path = _build(_C_SOURCE, "repro_native")
+    if path is None:
+        return None
+    lib = ctypes.CDLL(path)
     d, i = ctypes.c_double, ctypes.c_int
     pd = np.ctypeslib.ndpointer(np.float64, flags="C_CONTIGUOUS")
     pi = np.ctypeslib.ndpointer(np.int64, flags="C_CONTIGUOUS")
@@ -330,3 +354,133 @@ class NativeBatch:
                 "decode_seconds": agg[:, 3], "prefill_seconds": agg[:, 4],
                 "spill_seconds": agg[:, 5], "energy": agg[:, 6],
                 "max_step": agg[:, 7]}
+
+
+# ------------------------------------------------------ MMPP arrival sampler --
+
+_MMPP_SOURCE = r"""
+#include <math.h>
+#include <stdint.h>
+#include <stdlib.h>
+#include "numpy/random/bitgen.h"
+
+/* numpy/random/distributions.h includes Python.h: declare the two
+   libnpyrandom.a functions Generator.exponential/.poisson call, and
+   the header's inline next_double behind Generator.uniform. */
+extern double random_exponential(bitgen_t *bitgen_state, double scale);
+extern int64_t random_poisson(bitgen_t *bitgen_state, double lam);
+
+static inline double next_double(bitgen_t *bitgen_state) {
+    return bitgen_state->next_double(bitgen_state->state);
+}
+
+static int cmp_double(const void *a, const void *b) {
+    double x = *(const double *)a, y = *(const double *)b;
+    return (x > y) - (x < y);
+}
+
+/* The sojourn loop of workload._mmpp_arrivals_py, draw for draw. Returns
+   0, or which draw numpy would refuse (1 exponential scale, 2 poisson
+   lam, 3 uniform range) with the refused value in *bad. */
+int mmpp_arrivals(bitgen_t *bg, double rate_lo, double rate_hi,
+                  int64_t n, double mean_sojourn_s, double lam_max,
+                  double *out, double *bad) {
+    double t = 0.0;
+    int hi = 0;
+    int64_t total = 0;
+    if (!isnan(mean_sojourn_s) && signbit(mean_sojourn_s)) {
+        *bad = mean_sojourn_s;
+        return 1;
+    }
+    while (total < n) {
+        double dwell = random_exponential(bg, mean_sojourn_s);
+        double lam = (hi ? rate_hi : rate_lo) * dwell;
+        if (!(lam >= 0.0) || lam > lam_max) { *bad = lam; return 2; }
+        int64_t k = random_poisson(bg, lam);
+        int64_t need = n - total;
+        int64_t m = k > need ? need : k;
+        double range = k > need ? dwell * (double)need / (double)k : dwell;
+        if (m) {
+            if (!isfinite(range) || signbit(range)) { *bad = range; return 3; }
+            double *x = out + total;
+            for (int64_t i = 0; i < m; i++)
+                x[i] = 0.0 + range * next_double(bg);
+            qsort(x, (size_t)m, sizeof(double), cmp_double);
+            for (int64_t i = 0; i < m; i++)
+                x[i] = t + x[i];
+            total += m;
+        }
+        t += dwell;
+        hi = !hi;
+    }
+    return 0;
+}
+"""
+
+# numpy's own bound on Generator.poisson's lam (numpy/random/_common.pyx)
+_POISSON_LAM_MAX = float(np.iinfo("l").max - np.sqrt(np.iinfo("l").max) * 10)
+
+_mmpp_lib: Optional[object] = None
+_mmpp_tried = False
+
+
+def _compile_mmpp() -> Optional[object]:
+    """Build (or reuse) the sampler's shared object, linked against the
+    installed numpy's libnpyrandom.a; None on any failure. Its own object,
+    so a failed link never costs the replay engine."""
+    root = os.path.dirname(np.__file__)
+    static = os.path.join(root, "random", "lib", "libnpyrandom.a")
+    include = os.path.join(root, "_core", "include")
+    if not (os.path.exists(static) and os.path.exists(
+            os.path.join(include, "numpy", "random", "bitgen.h"))):
+        return None
+    with open(static, "rb") as f:
+        key = np.__version__.encode() + f.read()
+    path = _build(_MMPP_SOURCE, "repro_mmpp", (f"-I{include}", static, "-lm"),
+                  key)
+    if path is None:
+        return None
+    lib = ctypes.CDLL(path)
+    d = ctypes.c_double
+    lib.mmpp_arrivals.restype = ctypes.c_int
+    lib.mmpp_arrivals.argtypes = [
+        ctypes.c_void_p, d, d, ctypes.c_int64, d, d,
+        np.ctypeslib.ndpointer(np.float64, flags="C_CONTIGUOUS"),
+        ctypes.POINTER(d)]
+    return lib
+
+
+def mmpp_sampler() -> Optional[object]:
+    """The compiled MMPP sampler, or None where it did not build (cached
+    per process)."""
+    global _mmpp_lib, _mmpp_tried
+    if not _mmpp_tried:
+        _mmpp_tried = True
+        try:
+            _mmpp_lib = _compile_mmpp()
+        except Exception:
+            _mmpp_lib = None
+    return _mmpp_lib
+
+
+def mmpp_arrivals(lib: object, rate_lo: float, rate_hi: float, n: int,
+                  rng: np.random.Generator, mean_sojourn_s: float
+                  ) -> np.ndarray:
+    """`workload.mmpp_arrivals` run by the compiled sampler `lib` on
+    `rng`'s own bit generator: the same bytes, and `rng` left in the same
+    state. A draw numpy would refuse raises numpy's own exception."""
+    out = np.empty(n, np.float64)
+    bad = ctypes.c_double()
+    bitgen = rng.bit_generator
+    with bitgen.lock:
+        code = lib.mmpp_arrivals(bitgen.ctypes.bit_generator,
+                                 float(rate_lo), float(rate_hi), n,
+                                 float(mean_sojourn_s), _POISSON_LAM_MAX,
+                                 out, ctypes.byref(bad))
+    if code:
+        # numpy's argument check raises what the Python loop would have
+        refuse = (rng.exponential, rng.poisson,
+                  lambda r: rng.uniform(0.0, r))[code - 1]
+        refuse(bad.value)
+        raise AssertionError(f"numpy accepted {bad.value!r} (code {code})")
+    return out

@@ -18,6 +18,16 @@ replays:
 Everything draws from an explicit ``np.random.Generator`` seeded by the
 caller, so a (model, n, seed) triple always produces the same trace —
 golden fixtures and the SLO bisection both depend on that determinism.
+
+The MMPP sampler is a loop over sojourns, and a low-rate probe of the
+capacity bisection spans tens of thousands of them. Where the system C
+compiler links it against the installed numpy's ``libnpyrandom.a``, the
+loop runs in C (`traffic.native`) on the Generator's own bit generator,
+calling numpy's own exponential and poisson and the uniform's
+``next_double`` in the Python loop's order: the same bytes, draw for
+draw, and the same Generator state afterwards, so the length draws that
+follow are unchanged too. Otherwise the Python loop runs. The counters
+``sample.mmpp_native`` / ``sample.mmpp_fallback`` say which did.
 """
 from __future__ import annotations
 
@@ -25,6 +35,8 @@ import dataclasses
 from typing import Optional, Sequence, Tuple
 
 import numpy as np
+
+from repro.obs.metrics import metrics as _obs_metrics
 
 ARRIVALS = ("poisson", "mmpp", "trace", "scheduled")
 LENGTHS = ("lognormal", "buckets", "const")
@@ -187,7 +199,31 @@ def mmpp_arrivals(rate_lo: float, rate_hi: float, n: int,
     sojourn arrivals are Poisson at the state's rate (uniform order
     statistics over the sojourn). Index-of-dispersion > 1 — burstier than
     any single Poisson at the same mean rate.
+
+    The compiled sampler (`traffic.native.mmpp_sampler`) runs the sojourn
+    loop when it built, else `_mmpp_arrivals_py` does; both give the same
+    bytes and leave `rng` in the same state.
     """
+    if not (0.0 < rate_lo <= rate_hi):
+        raise ValueError(f"need 0 < rate_lo <= rate_hi, got "
+                         f"({rate_lo}, {rate_hi})")
+    if n < 1:
+        raise ValueError(f"n must be >= 1, got {n}")
+    from repro.traffic import native      # native imports this module
+    lib = native.mmpp_sampler()
+    if lib is None:
+        _obs_metrics().inc("sample.mmpp_fallback")
+        return _mmpp_arrivals_py(rate_lo, rate_hi, n, rng, mean_sojourn_s)
+    _obs_metrics().inc("sample.mmpp_native")
+    return native.mmpp_arrivals(lib, rate_lo, rate_hi, n, rng,
+                                mean_sojourn_s)
+
+
+def _mmpp_arrivals_py(rate_lo: float, rate_hi: float, n: int,
+                      rng: np.random.Generator, mean_sojourn_s: float = 10.0
+                      ) -> np.ndarray:
+    """The sojourn loop in Python: one iteration per sojourn, each a
+    scalar exponential and poisson draw, then the sojourn's uniforms."""
     if not (0.0 < rate_lo <= rate_hi):
         raise ValueError(f"need 0 < rate_lo <= rate_hi, got "
                          f"({rate_lo}, {rate_hi})")

@@ -97,16 +97,22 @@ def _summaries_equal(a, b):
             assert va == vb, (k, va, vb)
 
 
-@pytest.mark.parametrize("arrival", ["poisson", "mmpp"])
-def test_batched_capacity_bit_identical(arrival):
+@pytest.mark.parametrize("arrival", ["poisson", "mmpp", "mmpp_fallback"])
+def test_batched_capacity_bit_identical(monkeypatch, arrival):
+    """Batched == sequential search. `mmpp_fallback` runs the batched
+    search on the Python MMPP loop against a sequential search on the
+    compiled sampler (where it built): the same (qps, summary)."""
+    from repro.traffic import native
     ts = _tables()
-    tm = TrafficModel(arrival=arrival)
+    tm = TrafficModel(arrival=arrival.split("_")[0])
     slo = SLO(ttft_s=2.0, tpot_s=0.1)
     sim = SimConfig()
     tables = [ts.table(a, h, w) for a in ARCHS for h, w in HW]
     traffics = [tm] * len(tables)
     seq = [max_sustainable_qps(t, tr, slo, sim=sim, n_requests=200, seed=0)
            for t, tr in zip(tables, traffics)]
+    if arrival == "mmpp_fallback":
+        monkeypatch.setattr(native, "mmpp_sampler", lambda: None)
     for backend in ("xla", "scalar"):
         bat = batched_max_sustainable_qps(tables, traffics, slo, sim=sim,
                                           n_requests=200, seed=0,

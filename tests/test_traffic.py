@@ -15,7 +15,9 @@ from repro.traffic import (SLO, SimConfig, TrafficModel, build_cost_tables,
                            bucket_lengths, lognormal_lengths,
                            max_sustainable_qps, mmpp_arrivals,
                            poisson_arrivals, simulate)
-from repro.traffic.workload import RequestTrace
+from repro.obs.metrics import metrics as obs_metrics
+from repro.traffic import native
+from repro.traffic.workload import RequestTrace, _mmpp_arrivals_py
 
 from _hyp import given, settings, st
 
@@ -58,6 +60,73 @@ def test_mmpp_is_burstier_than_poisson():
     mmpp = mmpp_arrivals(16.0, 64.0, 40_000, rng, mean_sojourn_s=5.0)
     assert iod(pois) < 2.0 < iod(mmpp)
     assert (np.diff(mmpp) >= 0).all()
+
+
+def _mmpp_rates(qps, ratio=4.0):
+    lo = 2.0 * qps / (1.0 + ratio)          # as TrafficModel.sample
+    return lo, lo * ratio
+
+
+@pytest.mark.parametrize("seed", [0, 1, 3_000_000_001])
+@pytest.mark.parametrize("paired", [False, True])
+@pytest.mark.parametrize("qps", [5e-4, 1e-2, 1.0, 300.0, 5e3])
+def test_native_mmpp_sampler_matches_python_loop(monkeypatch, qps, paired,
+                                                 seed):
+    """The compiled sojourn loop draws the same bytes as the Python loop,
+    from the bisection's lowest probe rates to overshooting sojourns, and
+    leaves the Generator in the same state (so the lengths match too)."""
+    lib = native.mmpp_sampler()
+    if lib is None:
+        pytest.skip("the native MMPP sampler did not build")
+    tm = TrafficModel(arrival="mmpp").with_rate(qps)
+    a = tm.sample(1200, seed, paired=paired)
+    monkeypatch.setattr(native, "mmpp_sampler", lambda: None)
+    b = tm.sample(1200, seed, paired=paired)
+    for f in ("arrival_s", "prompt_len", "output_len"):
+        assert getattr(a, f).tobytes() == getattr(b, f).tobytes(), f
+    r_c, r_py = np.random.default_rng(seed), np.random.default_rng(seed)
+    x = native.mmpp_arrivals(lib, *_mmpp_rates(qps), 1200, r_c, 10.0)
+    y = _mmpp_arrivals_py(*_mmpp_rates(qps), 1200, r_py, 10.0)
+    assert x.tobytes() == y.tobytes()
+    assert r_c.bit_generator.state == r_py.bit_generator.state
+
+
+def test_mmpp_fallback_same_bytes_and_counted(monkeypatch):
+    """With no compiled sampler the Python loop draws the same trace; the
+    counters name the path each call took."""
+    reg = obs_metrics()
+    tm = TrafficModel(arrival="mmpp").with_rate(0.05)
+    before = reg.snapshot()
+    a = tm.sample(300, 4)
+    built = native.mmpp_sampler() is not None
+    assert reg.delta(before) == {("sample.mmpp_native" if built else
+                                  "sample.mmpp_fallback"): 1.0}
+    monkeypatch.setattr(native, "mmpp_sampler", lambda: None)
+    before = reg.snapshot()
+    b = tm.sample(300, 4)
+    assert reg.delta(before) == {"sample.mmpp_fallback": 1.0}
+    for f in ("arrival_s", "prompt_len", "output_len"):
+        assert getattr(a, f).tobytes() == getattr(b, f).tobytes(), f
+
+
+@pytest.mark.parametrize("rate_lo,rate_hi,sojourn", [
+    (1e300, 1e300, 10.0),               # lam too large on the first draw
+    (1.0, 1e300, 10.0),                 # ... on the first high sojourn
+    (np.inf, np.inf, 10.0),
+    (1.0, 4.0, np.nan),                 # lam is NaN
+    (1.0, 4.0, np.inf),
+    (1.0, 4.0, -1.0),                   # exponential refuses the scale
+])
+def test_mmpp_refused_draw_raises_alike_on_both_paths(monkeypatch, rate_lo,
+                                                      rate_hi, sojourn):
+    def refusal():
+        with pytest.raises((ValueError, OverflowError)) as e:
+            mmpp_arrivals(rate_lo, rate_hi, 100, np.random.default_rng(0),
+                          mean_sojourn_s=sojourn)
+        return type(e.value), str(e.value)
+    first = refusal()
+    monkeypatch.setattr(native, "mmpp_sampler", lambda: None)
+    assert refusal() == first
 
 
 def test_length_distributions():
