@@ -4,7 +4,9 @@ batch 1), lowered to GEMM workloads.
 Models (paper §4.2): AlexNet, VGG-16, GoogLeNet, BN-Inception, ResNet-152,
 DenseNet-201, ResNeXt-152 (g=32), MobileNetV3-Large, EfficientNet-B0.
 Tables follow the original publications; pooling/activation layers carry no
-GEMMs and are omitted (the systolic model sees matrix multiplies only).
+GEMMs and are omitted (the systolic model sees matrix multiplies only). The
+squeeze-and-excitation blocks of MobileNetV3 and EfficientNet lower to their
+two batch-1 FC layers each.
 
 These flat lists erase connectivity (skip/concat/branch edges) and with it
 the Unified-Buffer residency cost of each network. The graph-IR builders in
@@ -155,30 +157,51 @@ def densenet201(k: int = 32) -> List[Workload]:
     return lower(ls)
 
 
+def make_divisible(v: float, divisor: int = 8) -> int:
+    """Round a channel count to the nearest multiple of `divisor`, never
+    below it nor more than 10% under `v` (the rounding of the MobileNet
+    authors' reference code)."""
+    new = max(divisor, int(v + divisor / 2) // divisor * divisor)
+    return new + divisor if new < 0.9 * v else new
+
+
+def _squeeze_excite(c: int, se: int) -> List[FC]:
+    """Squeeze-and-excitation (Hu et al., arXiv:1709.01507) on a block's
+    depthwise output: two batch-1 FC layers, c -> se -> c. Its global
+    pooling and its gating multiply carry no GEMM."""
+    return [FC(c, se), FC(se, c)]
+
+
 def mobilenetv3_large() -> List[Workload]:
-    """MBConv rows: (h_in, c_in, exp, c_out, k, stride). Depthwise = groups=exp."""
+    """Howard et al., arXiv:1905.02244, Table 1. Bneck rows: (h_in, c_in,
+    exp, c_out, k, stride, se). Depthwise = groups=exp. SE squeezes to
+    make_divisible(exp // 4, 8), as the authors' reference code does."""
     rows = [
-        (112, 16, 16, 16, 3, 1),
-        (112, 16, 64, 24, 3, 2), (56, 24, 72, 24, 3, 1),
-        (56, 24, 72, 40, 5, 2), (28, 40, 120, 40, 5, 1),
-        (28, 40, 120, 40, 5, 1),
-        (28, 40, 240, 80, 3, 2), (14, 80, 200, 80, 3, 1),
-        (14, 80, 184, 80, 3, 1), (14, 80, 184, 80, 3, 1),
-        (14, 80, 480, 112, 3, 1), (14, 112, 672, 112, 3, 1),
-        (14, 112, 672, 160, 5, 2), (7, 160, 960, 160, 5, 1),
-        (7, 160, 960, 160, 5, 1),
+        (112, 16, 16, 16, 3, 1, False),
+        (112, 16, 64, 24, 3, 2, False), (56, 24, 72, 24, 3, 1, False),
+        (56, 24, 72, 40, 5, 2, True), (28, 40, 120, 40, 5, 1, True),
+        (28, 40, 120, 40, 5, 1, True),
+        (28, 40, 240, 80, 3, 2, False), (14, 80, 200, 80, 3, 1, False),
+        (14, 80, 184, 80, 3, 1, False), (14, 80, 184, 80, 3, 1, False),
+        (14, 80, 480, 112, 3, 1, True), (14, 112, 672, 112, 3, 1, True),
+        (14, 112, 672, 160, 5, 2, True), (7, 160, 960, 160, 5, 1, True),
+        (7, 160, 960, 160, 5, 1, True),
     ]
     ls = [Conv(224, 3, 16, k=3, stride=2)]
-    for (h, cin, exp, cout, kk, s) in rows:
+    for (h, cin, exp, cout, kk, s, se) in rows:
         if exp != cin:
             ls.append(Conv(h, cin, exp, k=1))
         ls.append(Conv(h, exp, exp, k=kk, stride=s, groups=exp))  # depthwise
+        if se:
+            ls += _squeeze_excite(exp, make_divisible(exp // 4))
         ls.append(Conv(h // s, exp, cout, k=1))
     ls += [Conv(7, 160, 960, k=1), FC(960, 1280), FC(1280, 1000)]
     return lower(ls)
 
 
 def efficientnet_b0() -> List[Workload]:
+    """Tan & Le, arXiv:1905.11946, Table 1. Every MBConv block has SE,
+    squeezing to max(1, int(0.25 * the block's input channels))."""
     rows = [  # (h_in, c_in, c_out, expand, k, stride, repeats)
         (112, 32, 16, 1, 3, 1, 1),
         (112, 16, 24, 6, 3, 2, 2),
@@ -198,6 +221,7 @@ def efficientnet_b0() -> List[Workload]:
             if e != 1:
                 ls.append(Conv(hh, ci, exp, k=1))
             ls.append(Conv(hh, exp, exp, k=kk, stride=st, groups=exp))
+            ls += _squeeze_excite(exp, max(1, int(ci * 0.25)))
             ls.append(Conv(hh // st, exp, cout, k=1))
     ls += [Conv(7, 320, 1280, k=1), FC(1280, 1000)]
     return lower(ls)

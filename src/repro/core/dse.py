@@ -10,7 +10,8 @@
 * pareto_grid / pareto_nsga2: frontier of (cycles vs energy) and
   (cycles vs -utilization) (Fig. 3).
 * robust_config: averaged min-max-normalized (energy, cycles) across a model
-  mix, Pareto over configurations (Fig. 5).
+  mix, Pareto over configurations (Fig. 5): `robust_serving_config` of one
+  `scenario_sweep` over the models.
 * equal_pe_sweep: extreme aspect ratios at constant PE count (Fig. 6,
   Samajdar et al. comparison), on either backend.
 * capacity_sweep: the connectivity-aware (h, w, ub_kib) design space — the
@@ -252,23 +253,20 @@ def _normalize(x):
     return (x - lo) / (hi - lo) if hi > lo else np.zeros_like(x)
 
 
-def robust_config(model_workloads: Dict[str, Sequence[Workload]], **model_kw):
-    """Fig. 5: average of min-max-normalized (energy, cycles) per model,
-    then the Pareto set over the grid."""
-    hs = grid_axes()
-    H, W = np.meshgrid(hs, hs, indexing="ij")
-    e_acc = np.zeros_like(H, np.float64)
-    c_acc = np.zeros_like(H, np.float64)
-    for name, wls in model_workloads.items():
-        s = grid_sweep(wls, **model_kw)
-        e_acc += _normalize(s.energy)
-        c_acc += _normalize(s.cycles)
-    e_acc /= len(model_workloads)
-    c_acc /= len(model_workloads)
-    F = np.stack([e_acc.reshape(-1), c_acc.reshape(-1)], axis=1)
-    mask = pareto_mask(F)
-    configs = np.stack([H.reshape(-1), W.reshape(-1)], axis=1)
-    return configs, F, mask
+def robust_config(model_workloads: Dict[str, Sequence[Workload]],
+                  weights: Optional[Dict[str, float]] = None,
+                  backend: str = "numpy", **kw):
+    """Fig. 5: the (weighted) average of min-max-normalized (energy,
+    cycles) per model, then the Pareto set over the grid. Returns
+    (configs, F, mask) as `robust_serving_config` does.
+
+    The models are swept together by `scenario_sweep`: `backend="pallas"`
+    in ONE fused dispatch of the batched kernel over (model, h, w),
+    `backend="numpy"` in float64, one model at a time. `kw` (`hs`, `ws`,
+    `block_c`, model options) goes to `scenario_sweep`."""
+    return robust_serving_config(
+        scenario_sweep(model_workloads, backend=backend, fused=True, **kw),
+        weights)
 
 
 def equal_pe_sweep(model_workloads: Dict[str, Sequence[Workload]],
@@ -452,13 +450,15 @@ def robust_serving_config(sweep: ScenarioSweepResult,
                           weights: Optional[Dict[str, float]] = None):
     """Fig. 5 generalized to a serving mix: the (weighted) average of
     min-max-normalized (energy, cycles) per SCENARIO — phase x batch x
-    seq_len cells, not just models — then the Pareto set over the grid.
+    seq_len cells, or whole models (`robust_config`) — then the Pareto
+    set over the grid. Returns (configs, F, mask).
 
     `weights` maps scenario name -> traffic share; None means uniform.
     When a dict is given it must be COMPLETE over the swept scenarios
     (unknown names raise): a scenario's share may be 0.0 (no traffic),
     but it must be said explicitly — silently dropping unnamed cells
-    would turn a typo into a different mix."""
+    would turn a typo into a different mix. Traces `robust.normalize`
+    (the normalization and the Pareto mask)."""
     if weights is not None:
         unknown = set(weights) - set(sweep.names)
         missing = set(sweep.names) - set(weights)
@@ -467,21 +467,23 @@ def robust_serving_config(sweep: ScenarioSweepResult,
                 "robust_serving_config: weights must cover the swept "
                 f"scenarios exactly (unknown: {sorted(unknown)[:3]}, "
                 f"missing: {sorted(missing)[:3]})")
-    wsum = 0.0
-    e_acc = np.zeros_like(sweep.H, np.float64)
-    c_acc = np.zeros_like(sweep.H, np.float64)
-    for i, name in enumerate(sweep.names):
-        wt = 1.0 if weights is None else float(weights[name])
-        if wt == 0.0:
-            continue
-        e_acc += wt * _normalize(sweep.energy[i])
-        c_acc += wt * _normalize(sweep.cycles[i])
-        wsum += wt
-    if wsum == 0.0:
-        raise ValueError("robust_serving_config: all scenario weights zero")
-    F = np.stack([(e_acc / wsum).reshape(-1), (c_acc / wsum).reshape(-1)],
-                 axis=1)
-    mask = pareto_mask(F)
+    with _obs_tracer().span("robust.normalize", "dse"):
+        wsum = 0.0
+        e_acc = np.zeros_like(sweep.H, np.float64)
+        c_acc = np.zeros_like(sweep.H, np.float64)
+        for i, name in enumerate(sweep.names):
+            wt = 1.0 if weights is None else float(weights[name])
+            if wt == 0.0:
+                continue
+            e_acc += wt * _normalize(sweep.energy[i])
+            c_acc += wt * _normalize(sweep.cycles[i])
+            wsum += wt
+        if wsum == 0.0:
+            raise ValueError(
+                "robust_serving_config: all scenario weights zero")
+        F = np.stack([(e_acc / wsum).reshape(-1),
+                      (c_acc / wsum).reshape(-1)], axis=1)
+        mask = pareto_mask(F)
     configs = np.stack([sweep.H.reshape(-1), sweep.W.reshape(-1)], axis=1)
     return configs, F, mask
 

@@ -99,6 +99,12 @@ def tiling(xp, D, s, relaxed: bool = False):
     r = D - (T-1)*s in 1..s.  Edge tiles are partially occupied — this is
     where the paper's pow2 utilization effects come from.
 
+    D and s are whole numbers (a GEMM dimension, an array side), so D/s is
+    a multiple of 1/s and T = ceil((D - 1/2) / s) exactly. The half keeps
+    T exact where division is not correctly rounded: the TPU's float32
+    division can land just above a whole quotient (25088 / 224 = 112), and
+    ceil(D/s) would then count a tile of no rows.
+
     With `relaxed=True` the ceil is replaced by its continuous envelope
     T = max(D/s, 1): identical when D <= s, smooth in s (and D) elsewhere,
     with r -> s for D > s. This makes every downstream closed form
@@ -110,7 +116,7 @@ def tiling(xp, D, s, relaxed: bool = False):
     if relaxed:
         T = xp.maximum(D / s, 1.0)
     else:
-        T = xp.ceil(D / s)
+        T = xp.ceil((D - 0.5) / s)
     return T, D - (T - 1) * s
 
 
@@ -270,7 +276,7 @@ def multi_array_components(xp, M, K, N, h, w, opt: ModelOptions):
     movement sums all arrays; the activation stream REPLICATES per array —
     the energy/parallelism tension the TPU's single big array avoids."""
     P = float(opt.n_arrays)
-    Np = xp.ceil(N / P)
+    Np = tiling(xp, N, P)[0]
     comp = ws_components(xp, M, K, Np, h, w, opt)
     for key in ("macs", "ub_act", "ub_weight", "ub_out", "inter_act",
                 "inter_psum", "inter_wload", "intra_act", "intra_weight",

@@ -7,17 +7,17 @@ import numpy as np
 
 
 def pareto_mask(objectives: np.ndarray) -> np.ndarray:
-    """objectives: (n, k), all MINIMIZED. Returns bool mask of the frontier."""
-    n = objectives.shape[0]
-    mask = np.ones(n, bool)
-    for i in range(n):
-        if not mask[i]:
-            continue
-        dominates = (np.all(objectives <= objectives[i], axis=1)
-                     & np.any(objectives < objectives[i], axis=1))
-        if np.any(dominates & mask):
-            mask[i] = False
-    return mask
+    """objectives: (n, k), all MINIMIZED. Returns bool mask of the frontier:
+    the points that no point dominates (no worse in every objective and
+    better in one), from all n x n comparisons at once."""
+    F = np.asarray(objectives)
+    n = F.shape[0]
+    no_worse = np.ones((n, n), bool)       # [i, j]: point j <= point i
+    better = np.zeros((n, n), bool)
+    for f in F.T:
+        no_worse &= f[None, :] <= f[:, None]
+        better |= f[None, :] < f[:, None]
+    return ~(no_worse & better).any(1)
 
 
 def fast_non_dominated_sort(F: np.ndarray) -> np.ndarray:

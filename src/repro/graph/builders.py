@@ -45,6 +45,7 @@ from __future__ import annotations
 from typing import Callable, Dict, List, Sequence, Tuple
 
 from repro.configs.base import ArchConfig, ShapeConfig, resolve_dims
+from repro.core.cnn_zoo import make_divisible
 from repro.core.workloads import FC, Conv, Gemm
 from repro.graph.ir import Graph, Node, Tensor
 
@@ -89,6 +90,13 @@ class _B:
         out = self.g.node(srcs[0]).out
         return self.g.add(Node(self._name("add"), "add",
                                Tensor(out.shape, self.bits)), srcs)
+
+    def gate(self, src: str, scale: str) -> str:
+        """Channel-wise gating multiply: `src` scaled by a per-channel
+        vector `scale`; a join that materializes a tensor like `src`."""
+        out = self.g.node(src).out
+        return self.g.add(Node(self._name("gate"), "add",
+                               Tensor(out.shape, self.bits)), (src, scale))
 
     def concat(self, *srcs: str) -> str:
         shapes = [self.g.node(s).out.shape for s in srcs]
@@ -294,32 +302,38 @@ def densenet201(k: int = 32, act_bits: float = DEFAULT_ACT_BITS) -> Graph:
 
 # -------------------------------------------------------- inverted residual --
 
-def _mbconv(b: _B, src: str, h, cin, exp, cout, kk, s) -> str:
-    """Expand (if exp != cin) -> depthwise -> project, with a residual add
-    when the block preserves shape (stride 1, cin == cout)."""
+def _mbconv(b: _B, src: str, h, cin, exp, cout, kk, s, se=0) -> str:
+    """Expand (if exp != cin) -> depthwise -> squeeze-and-excitation (if
+    se: pool, FC exp -> se, FC se -> exp, gate) -> project, with a
+    residual add when the block preserves shape (stride 1, cin == cout)."""
     e = b.conv(src, Conv(h, cin, exp, k=1)) if exp != cin else src
     d = b.conv(e, Conv(h, exp, exp, k=kk, stride=s, groups=exp))
+    if se:
+        z = b.pool(d, (1, 1, exp))          # global average pool
+        z = b.fc(b.fc(z, FC(exp, se)), FC(se, exp))
+        d = b.gate(d, z)
     p = b.conv(d, Conv(h // s, exp, cout, k=1))
     return b.add(p, src) if (s == 1 and cin == cout) else p
 
 
 def mobilenetv3_large(act_bits: float = DEFAULT_ACT_BITS) -> Graph:
     rows = [
-        (112, 16, 16, 16, 3, 1),
-        (112, 16, 64, 24, 3, 2), (56, 24, 72, 24, 3, 1),
-        (56, 24, 72, 40, 5, 2), (28, 40, 120, 40, 5, 1),
-        (28, 40, 120, 40, 5, 1),
-        (28, 40, 240, 80, 3, 2), (14, 80, 200, 80, 3, 1),
-        (14, 80, 184, 80, 3, 1), (14, 80, 184, 80, 3, 1),
-        (14, 80, 480, 112, 3, 1), (14, 112, 672, 112, 3, 1),
-        (14, 112, 672, 160, 5, 2), (7, 160, 960, 160, 5, 1),
-        (7, 160, 960, 160, 5, 1),
+        (112, 16, 16, 16, 3, 1, False),
+        (112, 16, 64, 24, 3, 2, False), (56, 24, 72, 24, 3, 1, False),
+        (56, 24, 72, 40, 5, 2, True), (28, 40, 120, 40, 5, 1, True),
+        (28, 40, 120, 40, 5, 1, True),
+        (28, 40, 240, 80, 3, 2, False), (14, 80, 200, 80, 3, 1, False),
+        (14, 80, 184, 80, 3, 1, False), (14, 80, 184, 80, 3, 1, False),
+        (14, 80, 480, 112, 3, 1, True), (14, 112, 672, 112, 3, 1, True),
+        (14, 112, 672, 160, 5, 2, True), (7, 160, 960, 160, 5, 1, True),
+        (7, 160, 960, 160, 5, 1, True),
     ]
     b = _B("mobilenetv3_large", act_bits)
     x = b.input((224, 224, 3))
     c = b.conv(x, Conv(224, 3, 16, k=3, stride=2))
-    for (h, cin, exp, cout, kk, s) in rows:
-        c = _mbconv(b, c, h, cin, exp, cout, kk, s)
+    for (h, cin, exp, cout, kk, s, se) in rows:
+        c = _mbconv(b, c, h, cin, exp, cout, kk, s,
+                    make_divisible(exp // 4) if se else 0)
     c = b.conv(c, Conv(7, 160, 960, k=1))
     c = b.pool(c, (1, 1, 960))             # global average pool
     c = b.fc(c, FC(960, 1280))
@@ -345,7 +359,8 @@ def efficientnet_b0(act_bits: float = DEFAULT_ACT_BITS) -> Graph:
             ci = cin if i == 0 else cout
             st = s if i == 0 else 1
             hh = h if i == 0 else h // s
-            c = _mbconv(b, c, hh, ci, ci * e, cout, kk, st)
+            c = _mbconv(b, c, hh, ci, ci * e, cout, kk, st,
+                        max(1, int(ci * 0.25)))
     c = b.conv(c, Conv(7, 320, 1280, k=1))
     c = b.pool(c, (1, 1, 1280))            # global average pool
     b.fc(c, FC(1280, 1000))

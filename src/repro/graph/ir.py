@@ -225,8 +225,12 @@ class Graph:
                 assert src.out.elems == n.layer.d_in * n.layer.batch, \
                     f"{n.name}: {src.out.elems} != d_in {n.layer.d_in}"
             elif n.kind == "add":
-                sizes = {p.out.elems for p in preds}
-                assert len(sizes) == 1 and n.out.elems in sizes, \
+                # every input matches the output, or is a per-channel
+                # vector broadcast over it (a gating multiply)
+                assert all(p.out.elems == n.out.elems
+                           or p.out.elems == n.out.shape[-1]
+                           for p in preds) \
+                    and preds[0].out.elems == n.out.elems, \
                     f"{n.name}: mismatched join {[p.out.shape for p in preds]}"
             elif n.kind == "concat":
                 assert n.out.elems == sum(p.out.elems for p in preds), \

@@ -61,11 +61,17 @@ BLOCK_C = 8
 LAYER_CHUNK = 128
 
 
+def padded_length(L: int) -> int:
+    """Rows the kernels walk for an L-row layer table: L up to one chunk,
+    else L rounded up to a multiple of LAYER_CHUNK."""
+    return L if L <= LAYER_CHUNK else -(-L // LAYER_CHUNK) * LAYER_CHUNK
+
+
 def _pad_layers(layers):
-    """Pad the layer axis (second to last) of a float32 layer table up to a
-    multiple of LAYER_CHUNK when it is longer than one chunk."""
+    """Pad the layer axis (second to last) of a float32 layer table up to
+    `padded_length` rows."""
     L = layers.shape[-2]
-    pad = (-L) % LAYER_CHUNK if L > LAYER_CHUNK else 0
+    pad = padded_length(L) - L
     if not pad:
         return layers
     rows = jnp.broadcast_to(jnp.asarray(PAD_LAYER, jnp.float32),
@@ -167,11 +173,6 @@ def dse_eval(configs, layers, *, block_c: int = BLOCK_C,
     )(configs.astype(jnp.float32), layers)
 
 
-def _kernel_batched(cfg_ref, layers_ref, out_ref, **opts):
-    out_ref[...] = _eval_block(cfg_ref[:, 0], cfg_ref[:, 1],
-                               layers_ref.at[0], **opts)[None]
-
-
 @functools.partial(
     jax.jit,
     static_argnames=("block_c", "interpret", "dataflow", "precision",
@@ -194,18 +195,22 @@ def dse_eval_batched(configs, layer_sets, *, block_c: int = BLOCK_C,
     layer_sets = _pad_layers(layer_sets.astype(jnp.float32))
     S, L, _ = layer_sets.shape
     kernel = functools.partial(
-        _kernel_batched, dataflow=dataflow, precision=precision,
+        _kernel, dataflow=dataflow, precision=precision,
         act_reread=act_reread,
         count_weight_load_hops=count_weight_load_hops,
         idle_pe_energy=idle_pe_energy, n_arrays=n_arrays)
+    # the scenario axis is squeezed out of both blocks (None), so the
+    # body is `dse_eval`'s: Mosaic refuses the chunk loop's row slices of
+    # a (1, L, 5) block ("slice shape along dimension 2 must be aligned
+    # to tiling (128)")
     return pl.pallas_call(
         kernel,
         grid=(S, C // block_c),
         in_specs=[
             pl.BlockSpec((block_c, 2), lambda s, i: (i, 0)),
-            pl.BlockSpec((1, L, 5), lambda s, i: (s, 0, 0)),
+            pl.BlockSpec((None, L, 5), lambda s, i: (s, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_c, len(OUT_COLS)),
+        out_specs=pl.BlockSpec((None, block_c, len(OUT_COLS)),
                                lambda s, i: (s, i, 0)),
         out_shape=jax.ShapeDtypeStruct((S, C, len(OUT_COLS)), jnp.float32),
         interpret=interpret,

@@ -13,7 +13,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.dse_eval import BLOCK_C, dse_eval, dse_eval_batched
+from repro.kernels.dse_eval import (BLOCK_C, dse_eval, dse_eval_batched,
+                                   padded_length)
 from repro.kernels.swa_attention import swa_attention
 from repro.kernels.ws_matmul import ws_matmul
 from repro.obs.metrics import metrics as _obs_metrics
@@ -73,6 +74,17 @@ def _pad(configs, block_c):
     return jnp.asarray(configs), C, block_c
 
 
+def _count_rows(layers):
+    """Count `sweep.layer_rows`, the real rows of a (..., L, 5) layer table
+    (PAD_LAYER rows carry groups * repeats == 0), and
+    `sweep.layer_rows_padded`, the rows the kernel walks once padded."""
+    t = np.asarray(layers)
+    m = _obs_metrics()
+    m.inc("sweep.layer_rows", int(np.count_nonzero(t[..., 3] * t[..., 4])))
+    m.inc("sweep.layer_rows_padded",
+          int(np.prod(t.shape[:-2])) * padded_length(t.shape[-2]))
+
+
 def sweep(configs, layers, *, block_c=None, interpret=None, **model_kw):
     """DSE sweep kernel over a (C, 2) config list and an (L, 5) layer
     table -> (C, 8) OUT_COLS rows. Configs are padded to the kernel block
@@ -82,10 +94,12 @@ def sweep(configs, layers, *, block_c=None, interpret=None, **model_kw):
 
     Counts one `kernels.sweep_dispatches` per call — here in the plain
     wrapper, NOT inside the jitted `dse_eval` (which only runs its Python
-    body at trace time), so the counter reflects actual dispatches.
-    Traces `sweep.put` (padding and the copy to the device) and
-    `sweep.enqueue` (the kernel call and the slice, both asynchronous)."""
+    body at trace time), so the counter reflects actual dispatches — and
+    the table's rows (`_count_rows`). Traces `sweep.put` (padding and the
+    copy to the device) and `sweep.enqueue` (the kernel call and the
+    slice, both asynchronous)."""
     _obs_metrics().inc("kernels.sweep_dispatches")
+    _count_rows(layers)
     interpret = _default_interpret() if interpret is None else interpret
     tr = _obs_tracer()
     with tr.span("sweep.put", "dse"):
@@ -103,9 +117,11 @@ def sweep_batched(configs, layer_sets, *, block_c=None, interpret=None,
 
     Counts one `kernels.fused_dispatches` per call (in the wrapper, not
     the jitted body) — the counter the "ONE fused dispatch per sweep"
-    regression tests assert on. Traces `sweep.put` and `sweep.enqueue`
-    as `sweep` does."""
+    regression tests assert on — and the rows of every scenario's table
+    (`_count_rows`). Traces `sweep.put` and `sweep.enqueue` as `sweep`
+    does."""
     _obs_metrics().inc("kernels.fused_dispatches")
+    _count_rows(layer_sets)
     interpret = _default_interpret() if interpret is None else interpret
     tr = _obs_tracer()
     with tr.span("sweep.put", "dse"):

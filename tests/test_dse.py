@@ -19,6 +19,102 @@ def test_zoo_macs_match_literature():
         assert abs(g - lit) / lit < 0.2, f"{name}: {g:.2f} vs lit {lit}"
 
 
+@pytest.mark.parametrize("name,published", [("mobilenetv3_large", 219e6),
+                                             ("efficientnet_b0", 390e6)])
+def test_mobile_macs_match_their_papers(name, published):
+    """With their squeeze-and-excitation FC pairs, the mobile networks
+    come within 3% of the multiply-adds their papers report (Howard et
+    al. Table 3, Tan & Le Table 2)."""
+    assert abs(total_macs(get_workloads(name)) / published - 1) < 0.03
+
+
+def _se_pairs(rows):
+    """(exp, se) of every SE pair: a depthwise row (groups = exp, one
+    output channel per group) followed by FC(exp, se), FC(se, exp) and
+    the 1x1 projection from exp channels."""
+    pairs = []
+    for i, (m, k, n, g, _) in enumerate(rows[:-3]):
+        fc1, fc2, proj = rows[i + 1:i + 4]
+        if g > 1 and n == 1 and fc1[:2] == (1, g) and fc2 == (
+                1, fc1[2], g, 1, 1) and proj[1] == g and proj[3] == 1:
+            pairs.append((g, fc1[2]))
+    return pairs
+
+
+@pytest.mark.parametrize("name,exps,squeezes", [
+    ("mobilenetv3_large", [72, 120, 120, 480, 672, 672, 960, 960],
+     [24, 32, 32, 120, 168, 168, 240, 240]),
+    ("efficientnet_b0",
+     [32, 96, 144, 144, 240, 240, 480, 480, 480, 672, 672, 672, 1152,
+      1152, 1152, 1152],
+     [8, 4, 6, 6, 10, 10, 20, 20, 20, 28, 28, 28, 48, 48, 48, 48])])
+def test_se_pairs_as_published(name, exps, squeezes):
+    """MobileNetV3-Large: SE on the 8 bnecks Table 1 marks, squeezed to
+    make_divisible(exp // 4, 8); EfficientNet-B0: SE on all 16 MBConv
+    blocks, squeezed to a quarter of the block's input channels. Each
+    pair sits between the depthwise conv and the projection."""
+    rows = get_workloads(name)
+    assert _se_pairs(rows) == list(zip(exps, squeezes))
+    assert sum(r[0] == 1 and r[3] == 1 for r in rows) == 2 * len(exps) + (
+        2 if name == "mobilenetv3_large" else 1)
+
+
+def _random_tables(rng, lengths):
+    return {f"net{i}": [(int(rng.integers(1, 3000)), int(rng.integers(1, 2500)),
+                         int(rng.integers(1, 1200)), int(rng.integers(1, 4)),
+                         int(rng.integers(1, 3))) for _ in range(n)]
+            for i, n in enumerate(lengths)}
+
+
+def test_robust_config_is_one_fused_dispatch_for_the_nine_networks():
+    from repro import obs
+    axis = np.array([32, 96, 200])
+    before = obs.metrics().snapshot()
+    cfgs, F, mask = robust_config({n: ZOO[n]() for n in ZOO},
+                                  backend="pallas", hs=axis, ws=axis)
+    d = obs.metrics().delta(before)
+    assert d.get("kernels.fused_dispatches") == 1
+    assert "kernels.sweep_dispatches" not in d
+    assert d["sweep.layer_rows"] == 807
+    assert d["sweep.layer_rows_padded"] == 9 * 256
+    assert F.shape == (9, 2) and mask.any()
+
+
+def test_robust_config_backends_agree_on_ragged_tables():
+    """Tables of unequal length, two of them over one 128-row chunk, at
+    a small grid and drawn weights: the fused float32 dispatch gives the
+    float64 path's objectives to float32 roundoff, and its Pareto set."""
+    rng = np.random.default_rng(16)
+    mw = _random_tables(rng, (3, 40, 129, 200, 9))
+    weights = dict(zip(mw, rng.dirichlet(np.ones(len(mw)))))
+    axis = np.array([16, 48, 120, 256])
+    c_np, F_np, m_np = robust_config(mw, weights, hs=axis, ws=axis)
+    c_pl, F_pl, m_pl = robust_config(mw, weights, backend="pallas",
+                                     hs=axis, ws=axis)
+    np.testing.assert_array_equal(c_np, c_pl)
+    assert np.abs(F_pl - F_np).max() < 1e-5
+    assert (m_np == m_pl).all()
+
+
+def test_robust_config_weights_are_a_plain_weighted_mean():
+    axis = np.array([16, 64, 136, 256])
+    mw = {n: ZOO[n]() for n in ("alexnet", "googlenet", "efficientnet_b0")}
+    weights = {"alexnet": 0.5, "googlenet": 0.0, "efficientnet_b0": 2.0}
+
+    def norm(x):
+        return (x - x.min()) / (x.max() - x.min())
+    want = sum(w * np.stack([norm(s.energy.reshape(-1)),
+                             norm(s.cycles.reshape(-1))], 1)
+               for w, s in ((weights[n], grid_sweep(mw[n], axis, axis))
+                            for n in mw)) / 2.5
+    _, F, mask = robust_config(mw, weights, hs=axis, ws=axis)
+    np.testing.assert_allclose(F, want, rtol=0, atol=1e-12)
+    _, F_uniform, _ = robust_config(mw, hs=axis, ws=axis)
+    assert not np.allclose(F, F_uniform)
+    with pytest.raises(ValueError, match="cover"):
+        robust_config(mw, {"alexnet": 1.0}, hs=axis, ws=axis)
+
+
 def test_paper_claim_tall_narrow_energy_optimum():
     """Fig. 2/5: data-movement optimum has height > width."""
     s = grid_sweep(get_workloads("resnet152"))
@@ -72,6 +168,21 @@ def test_pareto_mask_correct():
     F = np.array([[1, 5], [2, 2], [5, 1], [3, 3], [6, 6]], float)
     m = pareto_mask(F)
     assert m.tolist() == [True, True, True, False, False]
+
+
+@settings(max_examples=30, deadline=None)
+@given(n=st.integers(1, 60), k=st.integers(1, 3), seed=st.integers(0, 100))
+def test_pareto_mask_matches_the_point_by_point_loop(n, k, seed):
+    """The broadcast frontier equals a loop that drops each point some
+    kept point dominates, ties and NaNs included."""
+    rng = np.random.default_rng(seed)
+    F = np.round(rng.uniform(size=(n, k)), 1)
+    F[rng.uniform(size=n) < 0.1, 0] = np.nan
+    want = np.ones(n, bool)
+    for i in range(n):
+        dom = (F <= F[i]).all(1) & (F < F[i]).any(1)
+        want[i] = not (dom & want).any()
+    assert pareto_mask(F).tolist() == want.tolist()
 
 
 def test_nsga2_recovers_grid_frontier():

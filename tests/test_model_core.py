@@ -21,6 +21,29 @@ def _cfgs(n=128):
     return np.stack([H.reshape(-1), W.reshape(-1)], 1)[:n]
 
 
+class _RoundsUp(np.ndarray):
+    """float32 values whose quotients land one ulp above the true one, as
+    the TPU's float32 division may."""
+
+    def __truediv__(self, other):
+        q = np.divide(np.asarray(self), np.asarray(other, np.float32),
+                      dtype=np.float32)
+        return np.nextafter(q, np.float32(np.inf)).view(_RoundsUp)
+
+
+def test_tiling_is_exact_when_division_rounds_up():
+    """Whole quotients (25088 / 224 = 112) must not gain a tile of no
+    rows when the division lands just above them; ragged ones keep
+    their edge tile."""
+    from repro.core.model_core import tiling
+    D = np.array([25088, 576, 4096, 27, 1, 200], np.float32)
+    s = np.array([224, 64, 256, 16, 16, 8], np.float32)
+    assert np.ceil(D.view(_RoundsUp) / s).tolist()[:3] == [113, 10, 17]
+    T, r = tiling(np, D.view(_RoundsUp), s)
+    assert np.asarray(T).tolist() == [112, 9, 16, 2, 1, 25]
+    assert np.asarray(r).tolist() == [224, 64, 256, 11, 1, 8]
+
+
 def test_registry_has_all_dataflows():
     assert set(list_dataflows()) >= {"ws", "os", "multi_array"}
 

@@ -206,6 +206,45 @@ def test_scenario_sweep_is_one_fused_dispatch():
     assert "kernels.sweep_dispatches" not in d
 
 
+def test_sweep_wrappers_count_real_and_walked_layer_rows():
+    """`sweep.layer_rows` counts the rows each call was given, less the
+    PAD_LAYER rows of a batch; `sweep.layer_rows_padded` the rows the
+    kernel walks: each table padded to the batch's longest, then to the
+    128-row chunks once longer than one."""
+    from repro.core import get_workloads
+    from repro.core.dse import pad_layer_sets
+    from repro.kernels import ops
+    cfgs = np.array([[64.0, 64.0], [128.0, 32.0]])
+    layers = np.asarray(get_workloads("resnet152"), np.float32)
+    before = obs.metrics().snapshot()
+    ops.sweep(cfgs, layers, block_c=2)
+    assert obs.metrics().delta(before) == {
+        "kernels.sweep_dispatches": 1, "sweep.layer_rows": 156,
+        "sweep.layer_rows_padded": 256}
+    sets = pad_layer_sets([get_workloads("alexnet"),
+                           get_workloads("mobilenetv3_large")])
+    before = obs.metrics().snapshot()
+    ops.sweep_batched(cfgs, sets, block_c=2)
+    assert obs.metrics().delta(before) == {
+        "kernels.fused_dispatches": 1, "sweep.layer_rows": 8 + 64,
+        "sweep.layer_rows_padded": 2 * 64}
+
+
+def test_robust_config_traces_its_normalization():
+    from repro.core import get_workloads
+    from repro.core.dse import robust_config
+    axis = np.array([64, 160, 256])
+    old = obs.set_tracer(obs.Tracer(enabled=True, clock="wall"))
+    try:
+        robust_config({n: get_workloads(n) for n in ("alexnet", "vgg16")},
+                      hs=axis, ws=axis)
+        names = [ev[obs.trace.NAME] for ev in obs.tracer().events]
+        assert names.count("robust.normalize") == 2       # begin, end
+        assert obs.tracer().open_spans() == {}
+    finally:
+        obs.set_tracer(old)
+
+
 def test_build_stage_tables_is_one_fused_dispatch():
     from repro.fleet.partition import build_stage_tables
     before = obs.metrics().snapshot()

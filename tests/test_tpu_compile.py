@@ -93,6 +93,22 @@ def test_batched_sweep_kernel_compiles_cost_table_shape(one_chip,
     assert "tpu_custom_call" in compiled.as_text()
 
 
+def test_batched_sweep_kernel_compiles_robust_shape(one_chip,
+                                                    no_compile_cache):
+    """The Fig. 5 study in one fused dispatch: the nine CNNs' tables
+    padded to the longest (DenseNet-201, 201 rows), which the kernel pads
+    to 256 and walks in two 128-row chunks, against the 961-point grid."""
+    from repro.core.cnn_zoo import ZOO
+    S, L = len(ZOO), max(len(f()) for f in ZOO.values())
+    C = -(-grid_axes().size ** 2 // BLOCK_C) * BLOCK_C
+    assert (S, L, C) == (9, 201, 968)
+    compiled = dse_eval_batched.lower(
+        _spec((C, 2), jnp.float32, one_chip),
+        _spec((S, L, 5), jnp.float32, one_chip),
+        block_c=BLOCK_C, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_lockstep_engine_compiles_yi9b_lanes(one_chip, no_compile_cache):
     """The x64 multi-lane replay engine for one yi-9b lane per DEFAULT_HW
     config at the capacity sweep's 1200-request probes."""
