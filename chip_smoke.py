@@ -82,18 +82,18 @@ def check_device():
 def grid_phase():
     from repro.core import get_workloads, grid_sweep
     from repro.kernels import ops
-    from repro.kernels.dse_eval import BLOCK_C, dse_eval
+    from repro.kernels.dse_eval import CONFIG_TILE, dse_eval
 
     wl = get_workloads("resnet152")
     pl = grid_sweep(wl, backend="pallas")
     ref = grid_sweep(wl, backend="numpy")
-    # the exact kernel program the sweep dispatched: padded configs,
-    # the resident layer table, the default block
-    C = -(-pl.H.size // BLOCK_C) * BLOCK_C
+    # the exact kernel program the sweep dispatched: configs padded to
+    # the kernel's tile, the layer table
+    C = -(-pl.H.size // CONFIG_TILE) * CONFIG_TILE
     text = dse_eval.lower(
         jax.ShapeDtypeStruct((C, 2), np.float32),
         jax.ShapeDtypeStruct((len(wl), 5), np.float32),
-        block_c=BLOCK_C, interpret=False).compile().as_text()
+        interpret=False).compile().as_text()
     mosaic = "tpu_custom_call" in text
     err = max(_max_rel(getattr(ref, k), getattr(pl, k))
               for k in ("cycles", "energy", "utilization", "m_ub",
@@ -101,7 +101,7 @@ def grid_phase():
     _require(ops.kernel_backend() == "mosaic", ops.kernel_backend())
     _require(mosaic, "sweep kernel did not lower to a tpu_custom_call")
     _require(err <= 1e-6, f"pallas vs numpy max rel err {err:.3e} > 1e-6")
-    return {"configs": pl.H.size, "layers": len(wl), "block_c": BLOCK_C,
+    return {"configs": pl.H.size, "layers": len(wl), "tile": CONFIG_TILE,
             "kernel": ops.kernel_backend(), "tpu_custom_call": mosaic,
             "max_rel_vs_numpy": f"{err:.3e}"}
 

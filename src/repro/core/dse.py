@@ -98,12 +98,12 @@ def _grid_sweep_numpy(workloads, hs, ws, H, W, **model_kw):
                        ub_bw_bits=grid(m.ub_bandwidth_bits))
 
 
-def _pallas_eval_configs(workloads, cfgs, block_c=None, **model_kw):
+def _pallas_eval_configs(workloads, cfgs, **model_kw):
     """Evaluate an arbitrary (C, 2) config list on the fused Pallas sweep
     kernel, returning a dict of per-config metric columns.
 
-    `kernels.ops` pads the config list to the kernel block and slices the
-    result back; off-TPU the kernel runs in interpret mode. Traces
+    `kernels.ops` pads the config list to the kernel's tile and slices
+    the result back; off-TPU the kernel runs in interpret mode. Traces
     `sweep.put` (the layer table), `sweep.fetch` (waiting for the device
     and the copy back) and `sweep.assemble` (the columns).
     """
@@ -114,18 +114,18 @@ def _pallas_eval_configs(workloads, cfgs, block_c=None, **model_kw):
     with tr.span("sweep.put", "dse"):
         layers = np.asarray(
             [(m, k, n, g, r) for (m, k, n, g, r) in workloads], np.float32)
-    out = ops.sweep(cfgs, layers, block_c=block_c, **model_kw)
+    out = ops.sweep(cfgs, layers, **model_kw)
     with tr.span("sweep.fetch", "dse"):
         out = np.asarray(out)
     with tr.span("sweep.assemble", "dse"):
         return {k: out[:, j] for j, k in enumerate(OUT_COLS)}
 
 
-def _grid_sweep_pallas(workloads, hs, ws, H, W, block_c=None, **model_kw):
+def _grid_sweep_pallas(workloads, hs, ws, H, W, **model_kw):
     """Dispatch the whole grid to the fused Pallas sweep kernel."""
     cfgs = np.stack([H.reshape(-1), W.reshape(-1)], axis=1)
     col = {k: v.reshape(H.shape) for k, v in _pallas_eval_configs(
-        workloads, cfgs, block_c=block_c, **model_kw).items()}
+        workloads, cfgs, **model_kw).items()}
     return SweepResult(hs=hs, ws=ws, H=H, W=W, cycles=col["cycles"],
                        energy=col["energy"],
                        utilization=col["utilization"], m_ub=col["m_ub"],
@@ -263,7 +263,7 @@ def robust_config(model_workloads: Dict[str, Sequence[Workload]],
     The models are swept together by `scenario_sweep`: `backend="pallas"`
     in ONE fused dispatch of the batched kernel over (model, h, w),
     `backend="numpy"` in float64, one model at a time. `kw` (`hs`, `ws`,
-    `block_c`, model options) goes to `scenario_sweep`."""
+    model options) goes to `scenario_sweep`."""
     return robust_serving_config(
         scenario_sweep(model_workloads, backend=backend, fused=True, **kw),
         weights)
@@ -360,8 +360,8 @@ class ScenarioSweepResult:
 
 def scenario_sweep(named_workloads, hs=None,
                    ws=None, backend: str = "pallas", fused: bool = True,
-                   block_c: Optional[int] = None, cache_hit: float = 0.0,
-                   spec_decode=None, **model_kw) -> ScenarioSweepResult:
+                   cache_hit: float = 0.0, spec_decode=None,
+                   **model_kw) -> ScenarioSweepResult:
     """Sweep the whole scenario matrix over the (h, w) grid.
 
     `backend="pallas"` with `fused=True` (the default) pads every
@@ -401,12 +401,11 @@ def scenario_sweep(named_workloads, hs=None,
                                configs=int(H.size))
     with _span:
         return _scenario_sweep_body(named_workloads, names, hs, ws, H, W,
-                                    shape, backend, fused, block_c,
-                                    model_kw)
+                                    shape, backend, fused, model_kw)
 
 
 def _scenario_sweep_body(named_workloads, names, hs, ws, H, W, shape,
-                         backend, fused, block_c, model_kw):
+                         backend, fused, model_kw):
     if backend == "numpy":
         grids = {k: np.empty(shape, np.float64) for k in _SWEEP_KEYS}
         for i, name in enumerate(names):
@@ -419,7 +418,7 @@ def _scenario_sweep_body(named_workloads, names, hs, ws, H, W, shape,
         cfgs = np.stack([H.reshape(-1), W.reshape(-1)], axis=1)
         for i, name in enumerate(names):
             col = _pallas_eval_configs(named_workloads[name], cfgs,
-                                       block_c=block_c, **model_kw)
+                                       **model_kw)
             col["ub_bw_bits"] = col.pop("ub_bandwidth_bits")
             for k in _SWEEP_KEYS:
                 grids[k][i] = col[k].reshape(H.shape)
@@ -431,8 +430,7 @@ def _scenario_sweep_body(named_workloads, names, hs, ws, H, W, shape,
         with tr.span("sweep.put", "dse"):
             layer_sets = pad_layer_sets([named_workloads[n] for n in names])
             cfgs = np.stack([H.reshape(-1), W.reshape(-1)], axis=1)
-        out = ops.sweep_batched(cfgs, layer_sets, block_c=block_c,
-                                **model_kw)
+        out = ops.sweep_batched(cfgs, layer_sets, **model_kw)
         with tr.span("sweep.fetch", "dse"):
             out = np.asarray(out)
         with tr.span("sweep.assemble", "dse"):

@@ -438,7 +438,6 @@ def build_stage_tables(archs: Optional[Sequence[str]] = None,
                        kv_lattice: Sequence[int] = DEFAULT_KV_LATTICE,
                        prompt_lattice: Sequence[int] = DEFAULT_PROMPT_LATTICE,
                        backend: str = "pallas",
-                       block_c: Optional[int] = None,
                        act_bits: float = DEFAULT_ACT_BITS,
                        **model_kw) -> StageTableSet:
     """Build per-block stage tables for every (arch, h, w, tp) point in
@@ -481,7 +480,7 @@ def build_stage_tables(archs: Optional[Sequence[str]] = None,
             metas.append((arch, cfg, kinds, distinct, tp, base))
 
     t0 = time.perf_counter()
-    cols = _eval_lattice(workload_lists, hw, backend, block_c, **model_kw)
+    cols = _eval_lattice(workload_lists, hw, backend, **model_kw)
     build_s = time.perf_counter() - t0
 
     tables: Dict[Tuple[str, int, int, int], StageTables] = {}

@@ -1,7 +1,7 @@
 """Design-space-exploration sweep as a Pallas kernel.
 
-Evaluates the CAMUY closed forms for a whole block of (h, w) configurations
-against a VMEM-resident layer table in one grid step — the TPU-native
+Evaluates the CAMUY closed forms for every (h, w) configuration of a
+sweep against one or more layer tables in one dispatch — the TPU-native
 version of the paper's config sweep (961 configs x O(100) layers).
 
 The closed forms are NOT duplicated here: the kernel body calls the same
@@ -11,25 +11,38 @@ act_reread, count_weight_load_hops, idle_pe_energy, per-operand bitwidths)
 is supported identically on both backends. Options are jit-static: each
 distinct option set compiles once.
 
-Inputs:
-  configs: (C, 2) float32 — (h, w) per design point, C % block_c == 0
-  layers:  (L, 5) float32 — (M, K, N, groups, repeats) per GEMM workload;
-           a table longer than LAYER_CHUNK is padded to a multiple of it
-Outputs:
-  (C, 8) float32 — OUT_COLS per design point (movement counters summed over
-  layers, ub_bw_bits maxed, utilization normalized by the PE count).
+`dse_eval_batched` is the one kernel:
+  configs:    (C, 2) float32 — (h, w) per design point, C a multiple of
+              CONFIG_TILE (`kernels.ops` pads to it)
+  layer_sets: (S, L, 5) float32 — (M, K, N, groups, repeats) rows, one
+              table per scenario, each padded to L with PAD_LAYER rows
+  returns     (S, C, 8) float32 — OUT_COLS per (scenario, design point):
+              movement counters summed over rows, ub_bw_bits maxed,
+              utilization normalized by the PE count.
+`dse_eval` is its S = 1 case, an (L, 5) table -> (C, 8).
 
-`dse_eval_batched` extends the same kernel body to BATCHED layer sets: a
-(S, L, 5) tensor of S padded per-scenario layer tables evaluated against
-the shared config list in ONE fused dispatch over the (scenario, config
-block) grid — the serving-scenario sweep (core/dse.scenario_sweep) runs the
-whole scenario matrix without a Python loop of per-scenario sweeps, and the
-traffic cost-table build (traffic/cost_table.py) lowers its full
-(arch x slot x kv-span / prompt) lattice the same way, one kernel call for
-every simulator lookup table. Padding rows are PAD_LAYER (1, 1, 1, 0, 0):
-groups*repeats == 0 zeroes every summed counter, and the per-cycle
-bandwidth/port maxima are masked on that same weight. `kernels.ops` pads
-config lists to the block for both kernels.
+Layout: configs on lanes. A grid step holds CONFIG_TILE configs as one
+(8, 128) tile of h and one of w, and walks one scenario's rows as
+scalars read from SMEM (the table flattened, one scenario's block a
+step) in a `fori_loop` that stops at the table's last row of weight
+groups * repeats > 0 (a scalar-prefetched length). Each row is broadcast
+to the tile and evaluated once; the summed counters add into (8, 128)
+carries, compensated (Kahan), so a long table's sums stay within
+float32 roundoff of the exact totals. PAD_LAYER rows (1, 1, 1, 0, 0)
+past a table's length are never walked; one inside it zeroes every
+summed counter, and the maxed bandwidth is masked on that same weight.
+
+Mosaic gives every intermediate of the closed forms its own scoped-VMEM
+buffer, about 11 KiB per (config, row) element evaluated at once (16
+MiB is the v5e's scoped limit). Here that is one row against one tile,
+whatever the table's length or the number of tables. Configs on
+sublanes against rows on lanes, the other way round, held a step to 8
+configs x 128 rows for the same reason, padded every table to the
+batch's longest and to whole 128-row chunks, and cost 11.95 us a step
+on a TPU v5e, where a row against a full tile here costs 0.046 us: 2x
+less per element even at 8 configs and 128 real rows, 255x at a full
+tile. `kernel_ns_per_elem.sweep` in `bench/` reads the kernel's time
+per (config, row) element.
 """
 from __future__ import annotations
 
@@ -38,9 +51,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.model_core import (Precision, analyze_gemm_core,
-                                   pe_multiplier)
+from repro.core.model_core import analyze_gemm_core, pe_multiplier
 
 OUT_COLS = ("cycles", "energy", "macs", "utilization", "m_ub", "m_inter_pe",
             "m_aa", "ub_bandwidth_bits")
@@ -50,171 +63,112 @@ _SUM_COLS = ("cycles", "energy", "macs", "m_ub", "m_inter_pe", "m_aa")
 # counter; the maxed bandwidth term is masked on that same weight.
 PAD_LAYER = (1.0, 1.0, 1.0, 0.0, 0.0)
 
-# Mosaic gives every intermediate of the closed forms its own scoped-VMEM
-# buffer: about 11 KiB per (config, layer) element of a grid step (a v5e
-# compile of a single pass over the table needs 16.8 MB at 8 configs x 192
-# layers and 33.5 MB at 128 x 18; the scoped limit is 16 MiB). So a grid
-# step takes one sublane tile of configs, and its body walks the resident
-# layer table one lane tile of layers at a time (~11 MiB live whatever the
-# table's length).
-BLOCK_C = 8
-LAYER_CHUNK = 128
+# Configs per grid step: one (8, 128) tile.
+TILE = (8, 128)
+CONFIG_TILE = TILE[0] * TILE[1]
 
 
-def padded_length(L: int) -> int:
-    """Rows the kernels walk for an L-row layer table: L up to one chunk,
-    else L rounded up to a multiple of LAYER_CHUNK."""
-    return L if L <= LAYER_CHUNK else -(-L // LAYER_CHUNK) * LAYER_CHUNK
+def walked_rows(xp, layer_sets):
+    """Per table of a (..., L, 5) layer-set array (namespace `xp`, numpy
+    or jax.numpy): the rows the kernel walks, up to and including the
+    last of groups * repeats > 0."""
+    g = layer_sets[..., 3] * layer_sets[..., 4] > 0.0
+    L = layer_sets.shape[-2]
+    return xp.max(xp.where(g, xp.arange(1, L + 1), 0), axis=-1)
 
 
-def _pad_layers(layers):
-    """Pad the layer axis (second to last) of a float32 layer table up to
-    `padded_length` rows."""
-    L = layers.shape[-2]
-    pad = padded_length(L) - L
-    if not pad:
-        return layers
-    rows = jnp.broadcast_to(jnp.asarray(PAD_LAYER, jnp.float32),
-                            layers.shape[:-2] + (pad, 5))
-    return jnp.concatenate([layers, rows], axis=-2)
+def _kernel(lens_ref, cfg_ref, rows_ref, out_ref, *, dataflow="ws",
+            n_arrays=1, **opts):
+    """One (8, 128) tile of h and of w vs one scenario's rows, read as
+    SMEM scalars up to its length -> (8, 8, 128), OUT_COLS first."""
+    h = cfg_ref[0]
+    w = cfg_ref[1]
 
+    def body(r, carry):
+        sums, comp, bw = carry
+        M, K, N, groups, repeats = (
+            jnp.full(TILE, rows_ref[0, 5 * r + j], jnp.float32)
+            for j in range(5))
+        g = groups * repeats
+        d = analyze_gemm_core(jnp, M, K, N, h, w, groups=g,
+                              dataflow=dataflow, n_arrays=n_arrays, **opts)
+        # Kahan: `comp` carries the low-order bits each addition drops
+        new_sums, new_comp = [], []
+        for s, c, k in zip(sums, comp, _SUM_COLS):
+            y = d[k] - c
+            t = s + y
+            new_comp.append((t - s) - y)
+            new_sums.append(t)
+        bw = jnp.maximum(bw, jnp.where(g > 0.0, d["ub_bandwidth_bits"],
+                                       0.0))
+        return tuple(new_sums), tuple(new_comp), bw
 
-def _layer_terms(h, w, layers, *, dataflow, precision, act_reread,
-                 count_weight_load_hops, idle_pe_energy, n_arrays):
-    """(block_c,) h/w vs (l, 5) layer rows -> the _SUM_COLS counters summed
-    over the rows and ub_bandwidth_bits maxed over them, each (block_c,)."""
-    M = layers[:, 0][None, :]
-    K = layers[:, 1][None, :]
-    N = layers[:, 2][None, :]
-    g = (layers[:, 3] * layers[:, 4])[None, :]
-    h = h[:, None]
-    w = w[:, None]
-    d = analyze_gemm_core(
-        jnp, M, K, N, h, w, dataflow=dataflow, groups=g,
-        precision=precision, act_reread=act_reread,
-        count_weight_load_hops=count_weight_load_hops,
-        idle_pe_energy=idle_pe_energy, n_arrays=n_arrays)
-    # terms independent of (h, w) — e.g. macs, UB word counts — come back
-    # (1, l); broadcast to the full (block_c, l) before reducing over layers.
-    # Padding rows carry groups*repeats == 0, which already zeroes the
-    # summed counters; the maxed per-cycle terms (bandwidth, ports) must be
-    # masked explicitly or a (1, 1, 1) pad row would dominate them.
-    full = (h.shape[0], layers.shape[0])
-    valid = g > 0.0
-    sums = tuple(jnp.sum(jnp.broadcast_to(d[k], full), axis=1)
-                 for k in _SUM_COLS)
-    bw = jnp.max(jnp.where(jnp.broadcast_to(valid, full),
-                           jnp.broadcast_to(d["ub_bandwidth_bits"], full),
-                           0.0), axis=1)
-    return sums, bw
-
-
-def _eval_block(h, w, layers_ref, **opts):
-    """(block_c,) h/w vs the resident (L, 5) layer table -> (block_c, 8)
-    metrics. A table longer than LAYER_CHUNK (padded to a multiple of it)
-    is reduced chunk by chunk in a loop, so the live set stays one chunk's
-    worth of intermediates."""
-    L = layers_ref.shape[0]
-    if L <= LAYER_CHUNK:
-        sums, bw = _layer_terms(h, w, layers_ref[...], **opts)
-    else:
-        def body(i, acc):
-            start = pl.multiple_of(i * LAYER_CHUNK, LAYER_CHUNK)
-            s, b = _layer_terms(
-                h, w, layers_ref[pl.ds(start, LAYER_CHUNK), :], **opts)
-            return (tuple(a + x for a, x in zip(acc[0], s)),
-                    jnp.maximum(acc[1], b))
-
-        zero = jnp.zeros_like(h)
-        sums, bw = jax.lax.fori_loop(0, L // LAYER_CHUNK, body,
-                                     ((zero,) * len(_SUM_COLS), zero))
+    zero = jnp.zeros(TILE, jnp.float32)
+    zeros = (zero,) * len(_SUM_COLS)
+    sums, _, bw = jax.lax.fori_loop(0, lens_ref[pl.program_id(0)], body,
+                                    (zeros, zeros, zero))
     cols = dict(zip(_SUM_COLS, sums))
-    pe = h * w * pe_multiplier(opts["dataflow"], opts["n_arrays"])
+    pe = h * w * pe_multiplier(dataflow, n_arrays)
     cols["utilization"] = cols["macs"] / jnp.maximum(cols["cycles"] * pe,
                                                      1.0)
     cols["ub_bandwidth_bits"] = bw
-    return jnp.stack([cols[k] for k in OUT_COLS], axis=1)
+    for j, k in enumerate(OUT_COLS):
+        out_ref[j] = cols[k]
 
 
-def _kernel(cfg_ref, layers_ref, out_ref, **opts):
-    out_ref[...] = _eval_block(cfg_ref[:, 0], cfg_ref[:, 1], layers_ref,
-                               **opts)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("block_c", "interpret", "dataflow", "precision",
-                     "act_reread", "count_weight_load_hops",
-                     "idle_pe_energy", "n_arrays"))
-def dse_eval(configs, layers, *, block_c: int = BLOCK_C,
-             interpret: bool = False, dataflow: str = "ws",
-             precision: Precision = None, act_reread: bool = False,
-             count_weight_load_hops: bool = False,
-             idle_pe_energy: float = 0.0, n_arrays: int = 1):
+def _dse_eval(configs, layer_sets, *, interpret: bool = False, **opts):
     C = configs.shape[0]
-    assert C % block_c == 0, (C, block_c)
-    layers = _pad_layers(layers.astype(jnp.float32))
-    L = layers.shape[0]
-    kernel = functools.partial(
-        _kernel, dataflow=dataflow, precision=precision,
-        act_reread=act_reread,
-        count_weight_load_hops=count_weight_load_hops,
-        idle_pe_energy=idle_pe_energy, n_arrays=n_arrays)
-    return pl.pallas_call(
-        kernel,
-        grid=(C // block_c,),
-        in_specs=[
-            pl.BlockSpec((block_c, 2), lambda i: (i, 0)),
-            pl.BlockSpec((L, 5), lambda i: (0, 0)),   # layer table resident
-        ],
-        out_specs=pl.BlockSpec((block_c, len(OUT_COLS)), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((C, len(OUT_COLS)), jnp.float32),
+    assert C % CONFIG_TILE == 0, (C, CONFIG_TILE)
+    S, L, _ = layer_sets.shape
+    layer_sets = layer_sets.astype(jnp.float32)
+    planes = configs.astype(jnp.float32).T.reshape(2, C // TILE[1], TILE[1])
+    # a table's SMEM block is (1, L * 5): Mosaic takes a block whose last
+    # two dimensions are the array's own, not a squeezed (L * 5,) one
+    out = pl.pallas_call(
+        functools.partial(_kernel, **opts),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(S, C // CONFIG_TILE),
+            in_specs=[
+                pl.BlockSpec((2,) + TILE, lambda s, i, lens: (0, i, 0)),
+                pl.BlockSpec((None, 1, L * 5), lambda s, i, lens: (s, 0, 0),
+                             memory_space=pltpu.SMEM),
+            ],
+            out_specs=pl.BlockSpec((None, len(OUT_COLS)) + TILE,
+                                   lambda s, i, lens: (s, 0, i, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct(
+            (S, len(OUT_COLS), C // TILE[1], TILE[1]), jnp.float32),
         interpret=interpret,
-    )(configs.astype(jnp.float32), layers)
+    )(walked_rows(jnp, layer_sets).astype(jnp.int32), planes,
+      layer_sets.reshape(S, 1, L * 5))
+    return out.reshape(S, len(OUT_COLS), C).transpose(0, 2, 1)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("block_c", "interpret", "dataflow", "precision",
-                     "act_reread", "count_weight_load_hops",
-                     "idle_pe_energy", "n_arrays"))
-def dse_eval_batched(configs, layer_sets, *, block_c: int = BLOCK_C,
-                     interpret: bool = False, dataflow: str = "ws",
-                     precision: Precision = None, act_reread: bool = False,
-                     count_weight_load_hops: bool = False,
-                     idle_pe_energy: float = 0.0, n_arrays: int = 1):
+_jit = functools.partial(
+    jax.jit, static_argnames=("interpret", "dataflow", "precision",
+                              "act_reread", "count_weight_load_hops",
+                              "idle_pe_energy", "n_arrays"))
+
+
+@_jit
+def dse_eval_batched(configs, layer_sets, **kw):
     """Fused sweep over S scenarios x C configs in a single dispatch.
 
-    configs: (C, 2) float32, C % block_c == 0 — shared (h, w) design points
+    configs: (C, 2) float32, C % CONFIG_TILE == 0 — shared (h, w) points
     layer_sets: (S, L, 5) float32 — one padded layer table per scenario
       (pad rows are PAD_LAYER; see module docstring)
+    kw: `interpret` and the model options of
+      `model_core.analyze_gemm_core`
     Returns (S, C, 8) float32 — OUT_COLS per (scenario, design point).
     """
-    C = configs.shape[0]
-    assert C % block_c == 0, (C, block_c)
-    layer_sets = _pad_layers(layer_sets.astype(jnp.float32))
-    S, L, _ = layer_sets.shape
-    kernel = functools.partial(
-        _kernel, dataflow=dataflow, precision=precision,
-        act_reread=act_reread,
-        count_weight_load_hops=count_weight_load_hops,
-        idle_pe_energy=idle_pe_energy, n_arrays=n_arrays)
-    # the scenario axis is squeezed out of both blocks (None), so the
-    # body is `dse_eval`'s: Mosaic refuses the chunk loop's row slices of
-    # a (1, L, 5) block ("slice shape along dimension 2 must be aligned
-    # to tiling (128)")
-    return pl.pallas_call(
-        kernel,
-        grid=(S, C // block_c),
-        in_specs=[
-            pl.BlockSpec((block_c, 2), lambda s, i: (i, 0)),
-            pl.BlockSpec((None, L, 5), lambda s, i: (s, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, block_c, len(OUT_COLS)),
-                               lambda s, i: (s, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((S, C, len(OUT_COLS)), jnp.float32),
-        interpret=interpret,
-    )(configs.astype(jnp.float32), layer_sets)
+    return _dse_eval(configs, layer_sets, **kw)
+
+
+@_jit
+def dse_eval(configs, layers, **kw):
+    """`dse_eval_batched` of one (L, 5) layer table -> (C, 8)."""
+    return _dse_eval(configs, layers[None], **kw)[0]
 
 
 def relaxed_objectives(workloads, objectives=("energy", "cycles"),

@@ -13,8 +13,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.dse_eval import (BLOCK_C, dse_eval, dse_eval_batched,
-                                   padded_length)
+from repro.kernels.dse_eval import (CONFIG_TILE, dse_eval,
+                                   dse_eval_batched, walked_rows)
 from repro.kernels.swa_attention import swa_attention
 from repro.kernels.ws_matmul import ws_matmul
 from repro.obs.metrics import metrics as _obs_metrics
@@ -60,72 +60,60 @@ def attention(q, k, v, *, window=None, block_q=128, block_kv=128,
                          block_kv=block_kv, interpret=interpret)
 
 
-def _pad(configs, block_c):
-    """Pad a (C, 2) config list up to a multiple of the kernel block by
-    repeating the last design point. The block is `block_c` if given, else
-    BLOCK_C, or C itself (a full-extent block) when C is smaller.
-    Returns (padded configs, C, block)."""
-    configs = np.asarray(configs, np.float32)
-    C = len(configs)
-    block_c = block_c or min(BLOCK_C, C)
-    pad = (-C) % block_c
-    if pad:
-        configs = np.concatenate([configs, np.repeat(configs[-1:], pad, 0)])
-    return jnp.asarray(configs), C, block_c
+def _dispatch(configs, layer_sets, interpret, kernel, model_kw):
+    """Count the call's rows, pad the configs to the kernel's tile and
+    enqueue `kernel`.
 
-
-def _count_rows(layers):
-    """Count `sweep.layer_rows`, the real rows of a (..., L, 5) layer table
-    (PAD_LAYER rows carry groups * repeats == 0), and
-    `sweep.layer_rows_padded`, the rows the kernel walks once padded."""
-    t = np.asarray(layers)
+    Counts `sweep.layer_rows`, the real rows given (PAD_LAYER rows left
+    out), `sweep.layer_rows_padded`, the rows the kernel walks (each
+    table up to its last real row), and `kernels.config_lane_dispatches`.
+    Configs are padded to a multiple of CONFIG_TILE by repeating the last
+    design point, and the result is sliced back to C. Traces `sweep.put`
+    (padding and the copy to the device) and `sweep.enqueue` (the kernel
+    call and the slice, both asynchronous)."""
+    t = np.asarray(layer_sets)
     m = _obs_metrics()
     m.inc("sweep.layer_rows", int(np.count_nonzero(t[..., 3] * t[..., 4])))
-    m.inc("sweep.layer_rows_padded",
-          int(np.prod(t.shape[:-2])) * padded_length(t.shape[-2]))
+    m.inc("sweep.layer_rows_padded", int(np.sum(walked_rows(np, t))))
+    m.inc("kernels.config_lane_dispatches")
+    interpret = _default_interpret() if interpret is None else interpret
+    tr = _obs_tracer()
+    with tr.span("sweep.put", "dse"):
+        configs = np.asarray(configs, np.float32)
+        C = len(configs)
+        pad = (-C) % CONFIG_TILE
+        if pad:
+            configs = np.concatenate([configs,
+                                      np.repeat(configs[-1:], pad, 0)])
+        configs = jnp.asarray(configs)
+    with tr.span("sweep.enqueue", "dse"):
+        out = kernel(configs, layer_sets, interpret=interpret, **model_kw)
+        return out[..., :C, :]
 
 
-def sweep(configs, layers, *, block_c=None, interpret=None, **model_kw):
+def sweep(configs, layers, *, interpret=None, **model_kw):
     """DSE sweep kernel over a (C, 2) config list and an (L, 5) layer
-    table -> (C, 8) OUT_COLS rows. Configs are padded to the kernel block
-    (repeating the last design point) and the result sliced back to C;
-    `model_kw` passes dataflow/precision/accounting options through to
-    the shared model core (see kernels/dse_eval.py).
+    table -> (C, 8) OUT_COLS rows. Configs are padded to the kernel's
+    tile (repeating the last design point) and the result sliced back to
+    C; `model_kw` passes dataflow/precision/accounting options through
+    to the shared model core (see kernels/dse_eval.py).
 
     Counts one `kernels.sweep_dispatches` per call — here in the plain
     wrapper, NOT inside the jitted `dse_eval` (which only runs its Python
     body at trace time), so the counter reflects actual dispatches — and
-    the table's rows (`_count_rows`). Traces `sweep.put` (padding and the
-    copy to the device) and `sweep.enqueue` (the kernel call and the
-    slice, both asynchronous)."""
+    the rows as `_dispatch` says."""
     _obs_metrics().inc("kernels.sweep_dispatches")
-    _count_rows(layers)
-    interpret = _default_interpret() if interpret is None else interpret
-    tr = _obs_tracer()
-    with tr.span("sweep.put", "dse"):
-        padded, C, block_c = _pad(configs, block_c)
-    with tr.span("sweep.enqueue", "dse"):
-        return dse_eval(padded, layers, block_c=block_c,
-                        interpret=interpret, **model_kw)[:C]
+    return _dispatch(configs, layers, interpret, dse_eval, model_kw)
 
 
-def sweep_batched(configs, layer_sets, *, block_c=None, interpret=None,
-                  **model_kw):
+def sweep_batched(configs, layer_sets, *, interpret=None, **model_kw):
     """Fused (scenario, config) sweep kernel over batched layer sets —
     S scenarios x C configs in one dispatch -> (S, C, 8); configs are
     padded and sliced as in `sweep` (see kernels/dse_eval.py).
 
     Counts one `kernels.fused_dispatches` per call (in the wrapper, not
     the jitted body) — the counter the "ONE fused dispatch per sweep"
-    regression tests assert on — and the rows of every scenario's table
-    (`_count_rows`). Traces `sweep.put` and `sweep.enqueue` as `sweep`
-    does."""
+    regression tests assert on — and the rows as `_dispatch` says."""
     _obs_metrics().inc("kernels.fused_dispatches")
-    _count_rows(layer_sets)
-    interpret = _default_interpret() if interpret is None else interpret
-    tr = _obs_tracer()
-    with tr.span("sweep.put", "dse"):
-        padded, C, block_c = _pad(configs, block_c)
-    with tr.span("sweep.enqueue", "dse"):
-        return dse_eval_batched(padded, layer_sets, block_c=block_c,
-                                interpret=interpret, **model_kw)[:, :C]
+    return _dispatch(configs, layer_sets, interpret, dse_eval_batched,
+                     model_kw)

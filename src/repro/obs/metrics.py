@@ -20,8 +20,11 @@ Counter catalog (the names the stack emits; see README "Observability"):
     model.gemm_evals           layer-level closed-form evaluations
     kernels.sweep_dispatches   fused Pallas sweep kernel calls (dse_eval)
     kernels.fused_dispatches   batched-sweep kernel calls (dse_eval_batched)
+    kernels.config_lane_dispatches  sweep-kernel calls on the config-lane
+                               layout (every call of either kind)
     sweep.layer_rows           real layer rows those calls were given
     sweep.layer_rows_padded    layer rows the kernels walk after padding
+                               (each table up to its last real row)
     sim.replays / sim.requests / sim.tokens_out
     sim.events                 discrete-event loop iterations (O(requests))
     sim.decode_steps           engine decode steps charged

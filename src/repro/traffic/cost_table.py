@@ -250,7 +250,7 @@ def build_cost_tables(archs: Optional[Sequence[str]] = None,
                       slot_lattice: Sequence[int] = DEFAULT_SLOT_LATTICE,
                       kv_lattice: Sequence[int] = DEFAULT_KV_LATTICE,
                       prompt_lattice: Sequence[int] = DEFAULT_PROMPT_LATTICE,
-                      backend: str = "pallas", block_c: Optional[int] = None,
+                      backend: str = "pallas",
                       act_bits: float = 8.0,
                       spec: Optional[SpecDecodeConfig] = None,
                       **model_kw) -> CostTableSet:
@@ -313,7 +313,7 @@ def build_cost_tables(archs: Optional[Sequence[str]] = None,
             metas.append((arch, kv_bits_per_token(cfg, act_bits)))
 
     t0 = time.perf_counter()
-    cols = _eval_lattice(workload_lists, hw, backend, block_c, **model_kw)
+    cols = _eval_lattice(workload_lists, hw, backend, **model_kw)
     build_s = time.perf_counter() - t0
 
     with tr.span("tables.assemble", "dse"):
@@ -360,7 +360,7 @@ def build_cost_tables(archs: Optional[Sequence[str]] = None,
                         backend=backend, build_seconds=build_s)
 
 
-def _eval_lattice(workload_lists, hw, backend, block_c, **model_kw):
+def _eval_lattice(workload_lists, hw, backend, **model_kw):
     """(S, C) metric columns for S lattice points x C configs."""
     cfgs = np.asarray(hw, np.float64)
     C = cfgs.shape[0]
@@ -382,8 +382,7 @@ def _eval_lattice(workload_lists, hw, backend, block_c, **model_kw):
         out = {k: np.empty((len(workload_lists), C), np.float64)
                for k in ("cycles", "energy", "macs")}
         for i, wls in enumerate(workload_lists):
-            col = _pallas_eval_configs(wls, cfgs, block_c=block_c,
-                                       **model_kw)
+            col = _pallas_eval_configs(wls, cfgs, **model_kw)
             for k in out:
                 out[k][i] = col[k]
         return out
@@ -394,8 +393,7 @@ def _eval_lattice(workload_lists, hw, backend, block_c, **model_kw):
         tr = _obs_tracer()
         with tr.span("sweep.put", "dse"):
             layer_sets = pad_layer_sets(workload_lists)
-        out = ops.sweep_batched(cfgs, layer_sets, block_c=block_c,
-                                **model_kw)
+        out = ops.sweep_batched(cfgs, layer_sets, **model_kw)
         with tr.span("sweep.fetch", "dse"):
             out = np.asarray(out)
         with tr.span("sweep.assemble", "dse"):

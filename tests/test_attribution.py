@@ -371,7 +371,7 @@ def test_disagg_fleet_breakdown_conserves_with_link_ship():
 
 def test_partitioned_fleet_breakdown_attributes_pipeline_bubble():
     st_tab = build_stage_tables([ARCH], hw=((64, 64), (128, 128)),
-                                tps=(1,), backend="numpy", block_c=2,
+                                tps=(1,), backend="numpy",
                                 **LAT).table(ARCH, 64, 64)
     part = partition_server_table(st_tab, n_stages=2, n_micro=4,
                                   link=LinkModel(bits_per_cycle=32.0))

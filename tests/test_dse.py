@@ -75,8 +75,9 @@ def test_robust_config_is_one_fused_dispatch_for_the_nine_networks():
     d = obs.metrics().delta(before)
     assert d.get("kernels.fused_dispatches") == 1
     assert "kernels.sweep_dispatches" not in d
+    assert d["kernels.config_lane_dispatches"] == 1
     assert d["sweep.layer_rows"] == 807
-    assert d["sweep.layer_rows_padded"] == 9 * 256
+    assert d["sweep.layer_rows_padded"] == 807     # no row is padding
     assert F.shape == (9, 2) and mask.any()
 
 

@@ -41,7 +41,7 @@ LATTICES = dict(slot_lattice=SLOTS, kv_lattice=KVS, prompt_lattice=PROMPTS)
 @functools.lru_cache(maxsize=None)
 def _stage_tables(arch="yi-9b", tp=1, backend="numpy"):
     return build_stage_tables([arch], hw=((64, 64), (128, 128)),
-                              tps=(tp,), backend=backend, block_c=2,
+                              tps=(tp,), backend=backend,
                               **LATTICES)
 
 
@@ -211,7 +211,7 @@ def test_stage_tables_fused_matches_numpy():
     per-stage reference loop (same bar as the traffic cost tables)."""
     st_np = _stage_tables(backend="numpy")
     st_pl = build_stage_tables(["yi-9b"], hw=((64, 64), (128, 128)),
-                               tps=(1,), backend="pallas", block_c=2,
+                               tps=(1,), backend="pallas",
                                **LATTICES)
     a = st_np.table("yi-9b", 128, 128)
     b = st_pl.table("yi-9b", 128, 128)

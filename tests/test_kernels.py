@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.core.model_core import Precision
 from repro.kernels import ops, ref
 
 MM_SHAPES = [(128, 128, 128), (256, 384, 128), (128, 512, 256)]
@@ -64,9 +65,9 @@ def test_swa_attention_bf16():
 @pytest.mark.parametrize("net,n_cfgs", [("resnet152", 896),
                                          ("densenet201", 961)])
 def test_dse_eval_vs_float64_model(net, n_cfgs):
-    """Both tables are longer than one layer chunk (156 and 201 rows), and
-    961 configs are not a multiple of the block: the padded tail and the
-    chunked reduction must both come back exact to f32 roundoff."""
+    """Long tables (156 and 201 rows) summed row by row, and 896 and 961
+    configs, neither a multiple of the 1,024-config tile: the padded tail
+    and the long sums must both come back exact to f32 roundoff."""
     from repro.core.cnn_zoo import get_workloads
     from repro.core.dse import grid_axes
     layers = np.asarray(get_workloads(net), np.float32)
@@ -79,6 +80,39 @@ def test_dse_eval_vs_float64_model(net, n_cfgs):
     want = ref.dse_eval_ref(cfgs, layers)
     rel = np.abs(got - want) / (np.abs(want) + 1.0)
     assert rel.max() < 1e-5
+
+
+@pytest.mark.parametrize("model_kw", [
+    {},
+    {"dataflow": "os"},
+    {"dataflow": "multi_array", "n_arrays": 4},
+    {"act_reread": True, "count_weight_load_hops": True},
+    {"idle_pe_energy": 0.2},
+    {"dataflow": "os", "precision": Precision(16, 4, 16)},
+    {"precision": Precision(4, 8, 16), "count_weight_load_hops": True},
+], ids=lambda kw: "-".join(map(str, kw.values())) or "default")
+def test_batched_kernel_vs_float64_model(model_kw):
+    """Ragged 3-, 9- and 130-row tables in one batched call on the 961
+    grid, under each dataflow and accounting option: every table summed
+    to its own length, a groups = 0 row inside the longest counted as
+    absent, against the float64 closed forms."""
+    from repro.core.cnn_zoo import get_workloads
+    from repro.core.dse import grid_axes, pad_layer_sets
+    rows = get_workloads("densenet201")
+    tables = [rows[:3], rows[3:12], rows[12:142]]
+    sets = pad_layer_sets(tables)
+    sets[2, 60, 3] = 0.0
+    hs = grid_axes()
+    H, W = np.meshgrid(hs, hs, indexing="ij")
+    cfgs = np.stack([H.reshape(-1), W.reshape(-1)], 1)
+    got = np.asarray(ops.sweep_batched(cfgs, sets, interpret=True,
+                                       **model_kw))
+    assert got.shape == (3, 961, 8)
+    for s, t in enumerate(tables):
+        layers = np.asarray(t[:60] + t[61:] if s == 2 else t, np.float32)
+        want = ref.dse_eval_ref(cfgs, layers, **model_kw)
+        rel = np.abs(got[s] - want) / (np.abs(want) + 1.0)
+        assert rel.max() < 1e-5, (s, model_kw, rel.max())
 
 
 @pytest.mark.parametrize("env_dir", [None, "/elsewhere/cache"])

@@ -80,7 +80,7 @@ def test_pallas_kernel_matches_numpy_core(model_kw):
 def test_grid_sweep_backends_match_on_full_resnet_sweep():
     """Acceptance: backend="pallas" matches backend="numpy" to <=1e-3
     relative error on the 961-config ResNet-152 sweep (961 is not a
-    multiple of the kernel block — exercises the auto-padding)."""
+    multiple of the kernel tile — exercises the auto-padding)."""
     wl = get_workloads("resnet152")
     s_np = grid_sweep(wl, backend="numpy")
     s_pl = grid_sweep(wl, backend="pallas")

@@ -25,8 +25,7 @@ PROMPTS = (16, 64, 256, 1024)
 def _tables():
     return build_cost_tables(archs=[ARCH], hw=((64, 64), (128, 128)),
                              slot_lattice=SLOTS, kv_lattice=KVS,
-                             prompt_lattice=PROMPTS, backend="numpy",
-                             block_c=2)
+                             prompt_lattice=PROMPTS, backend="numpy")
 
 
 def _trace(n=300, qps=40.0, seed=0):
@@ -200,7 +199,7 @@ def test_scenario_sweep_is_one_fused_dispatch():
              "b": get_workloads("resnet152")[:3]}
     before = obs.metrics().snapshot()
     scenario_sweep(named, hs=(16, 32), ws=(16, 32), backend="pallas",
-                   fused=True, block_c=2)
+                   fused=True)
     d = obs.metrics().delta(before)
     assert d.get("kernels.fused_dispatches") == 1
     assert "kernels.sweep_dispatches" not in d
@@ -209,25 +208,64 @@ def test_scenario_sweep_is_one_fused_dispatch():
 def test_sweep_wrappers_count_real_and_walked_layer_rows():
     """`sweep.layer_rows` counts the rows each call was given, less the
     PAD_LAYER rows of a batch; `sweep.layer_rows_padded` the rows the
-    kernel walks: each table padded to the batch's longest, then to the
-    128-row chunks once longer than one."""
+    kernel walks: each table up to its own last real row, so none of a
+    batch's padding."""
     from repro.core import get_workloads
     from repro.core.dse import pad_layer_sets
     from repro.kernels import ops
     cfgs = np.array([[64.0, 64.0], [128.0, 32.0]])
     layers = np.asarray(get_workloads("resnet152"), np.float32)
     before = obs.metrics().snapshot()
-    ops.sweep(cfgs, layers, block_c=2)
+    ops.sweep(cfgs, layers)
     assert obs.metrics().delta(before) == {
-        "kernels.sweep_dispatches": 1, "sweep.layer_rows": 156,
-        "sweep.layer_rows_padded": 256}
+        "kernels.sweep_dispatches": 1, "kernels.config_lane_dispatches": 1,
+        "sweep.layer_rows": 156, "sweep.layer_rows_padded": 156}
     sets = pad_layer_sets([get_workloads("alexnet"),
                            get_workloads("mobilenetv3_large")])
     before = obs.metrics().snapshot()
-    ops.sweep_batched(cfgs, sets, block_c=2)
+    ops.sweep_batched(cfgs, sets)
     assert obs.metrics().delta(before) == {
-        "kernels.fused_dispatches": 1, "sweep.layer_rows": 8 + 64,
-        "sweep.layer_rows_padded": 2 * 64}
+        "kernels.fused_dispatches": 1, "kernels.config_lane_dispatches": 1,
+        "sweep.layer_rows": 8 + 64, "sweep.layer_rows_padded": 8 + 64}
+
+
+def _tables_of(lengths, zero_rows=()):
+    """Padded (S, max(lengths), 5) layer sets of the given real lengths,
+    rows drawn from the CNN zoo; (table, row) pairs in `zero_rows` get
+    groups 0."""
+    from repro.core import get_workloads
+    from repro.core.dse import pad_layer_sets
+    rows = get_workloads("densenet201") + get_workloads("resnet152")
+    sets = pad_layer_sets([rows[i:i + n] for i, n in enumerate(lengths)])
+    for s, r in zero_rows:
+        sets[s, r, 3] = 0.0
+    return sets
+
+
+@pytest.mark.parametrize("lengths,n_cfgs,zero_rows", [
+    ((8, 12, 58, 70, 156, 156, 201, 64, 82), 961, ()),   # cnn_zoo.robust
+    ((9,) * 32, 961, ()),                                # scenario_grid
+    ((156,), 961, ()),                                   # resnet152.grid
+    ((18,) * 57, 8, ((3, 17), (5, 4))),                  # a cost table
+], ids=["robust", "scenario", "resnet", "cost_table"])
+def test_sweep_walks_each_table_to_its_last_real_row(lengths, n_cfgs,
+                                                     zero_rows):
+    """The sweeps' and the cost tables' shapes all take the config-lane
+    kernel, one call each, and walk every table to its own length: a row
+    of groups 0 inside a table is walked (and masked), one at its end is
+    not."""
+    from repro.kernels import ops
+    sets = _tables_of(lengths, zero_rows)
+    cfgs = np.stack([np.arange(n_cfgs) % 31 * 8.0 + 16,
+                     np.arange(n_cfgs) // 31 * 8.0 + 16], 1)
+    before = obs.metrics().snapshot()
+    out = ops.sweep_batched(cfgs, sets)
+    assert out.shape == (len(lengths), n_cfgs, 8)
+    ends = {s for s, r in zero_rows if r == lengths[s] - 1}
+    assert obs.metrics().delta(before) == {
+        "kernels.fused_dispatches": 1, "kernels.config_lane_dispatches": 1,
+        "sweep.layer_rows": sum(lengths) - len(zero_rows),
+        "sweep.layer_rows_padded": sum(lengths) - len(ends)}
 
 
 def test_robust_config_traces_its_normalization():
@@ -249,8 +287,8 @@ def test_build_stage_tables_is_one_fused_dispatch():
     from repro.fleet.partition import build_stage_tables
     before = obs.metrics().snapshot()
     build_stage_tables([ARCH], hw=((64, 64),), tps=(1,), backend="pallas",
-                       block_c=2, slot_lattice=SLOTS[:2],
-                       kv_lattice=KVS[:2], prompt_lattice=PROMPTS[:2])
+                       slot_lattice=SLOTS[:2], kv_lattice=KVS[:2],
+                       prompt_lattice=PROMPTS[:2])
     d = obs.metrics().delta(before)
     assert d.get("kernels.fused_dispatches") == 1
 

@@ -194,8 +194,7 @@ def test_scenario_sweep_fused_matches_numpy_full_matrix():
     _, nw = _matrix()
     assert len(nw) == 20
     s_np = scenario_sweep(nw, hs=SMALL, ws=SMALL, backend="numpy")
-    s_pl = scenario_sweep(nw, hs=SMALL, ws=SMALL, backend="pallas",
-                          block_c=SMALL.size ** 2)
+    s_pl = scenario_sweep(nw, hs=SMALL, ws=SMALL, backend="pallas")
     for k in ("cycles", "energy", "utilization", "m_ub", "m_inter_pe",
               "m_aa", "ub_bw_bits"):
         a = getattr(s_np, k)
@@ -209,9 +208,8 @@ def test_scenario_sweep_fused_matches_dispatch_loop():
     dispatch loop computes (same kernel body, same f32 math; the padding
     rows only add zeros to the sums and are masked out of the maxes)."""
     _, nw = _matrix()
-    fused = scenario_sweep(nw, hs=SMALL, ws=SMALL, block_c=SMALL.size ** 2)
-    loop = scenario_sweep(nw, hs=SMALL, ws=SMALL, fused=False,
-                          block_c=SMALL.size ** 2)
+    fused = scenario_sweep(nw, hs=SMALL, ws=SMALL)
+    loop = scenario_sweep(nw, hs=SMALL, ws=SMALL, fused=False)
     for k in ("cycles", "energy", "utilization", "m_ub", "m_inter_pe",
               "m_aa", "ub_bw_bits"):
         np.testing.assert_allclose(getattr(fused, k), getattr(loop, k),

@@ -30,7 +30,7 @@ def _tables():
                              slot_lattice=(1, 2, 4, 8),
                              kv_lattice=(64, 128, 256, 512),
                              prompt_lattice=(16, 64, 256, 1024),
-                             backend="numpy", block_c=2)
+                             backend="numpy")
 
 
 # ---------------------------------------------------- lockstep bisection ---

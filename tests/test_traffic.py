@@ -31,8 +31,7 @@ PROMPTS = (16, 64, 256, 1024)
 def _tables(backend="numpy"):
     return build_cost_tables(archs=[ARCH], hw=((64, 64), (128, 128)),
                              slot_lattice=SLOTS, kv_lattice=KVS,
-                             prompt_lattice=PROMPTS, backend=backend,
-                             block_c=2)
+                             prompt_lattice=PROMPTS, backend=backend)
 
 
 def _table():
@@ -230,11 +229,11 @@ def test_pallas_loop_backend_matches_fused():
     lp = build_cost_tables(archs=[ARCH], hw=((64, 64), (128, 128)),
                            slot_lattice=SLOTS[:2], kv_lattice=KVS[:2],
                            prompt_lattice=PROMPTS[:2],
-                           backend="pallas-loop", block_c=2)
+                           backend="pallas-loop")
     fu = build_cost_tables(archs=[ARCH], hw=((64, 64), (128, 128)),
                            slot_lattice=SLOTS[:2], kv_lattice=KVS[:2],
                            prompt_lattice=PROMPTS[:2],
-                           backend="pallas", block_c=2)
+                           backend="pallas")
     for key in fu.tables:
         np.testing.assert_allclose(lp.tables[key].decode_cycles,
                                    fu.tables[key].decode_cycles, rtol=1e-6)
