@@ -13,9 +13,8 @@ one chip, and checks each against its float64 / scalar reference:
       lattice in ONE fused dispatch — against `backend="numpy"` (1e-5);
   (d) `slo_capacity_sweep(search="auto")` for yi-9b and mixtral-8x22b:
       the max-QPS table must equal `search="sequential"`;
-  (e) the search engines: the x64 lockstep replay must refuse a TPU
-      (its emulated float64 is not bit-identical to the scalar simulator)
-      and `backend="auto"` must match the scalar search bitwise;
+  (e) the search engines: `backend="auto"` must pick the native C
+      replay and match the scalar search bitwise;
       `nsga2_device` (pop 64, gens 40) must match its numpy transcription
       bitwise; `refine_design_point` must never be worse than its seed.
 
@@ -149,23 +148,15 @@ def slo_phase(ts):
     return {"points": auto.max_qps.size, "auto_equals_sequential": same}
 
 
-def lockstep_phase(ts):
-    """The x64 lockstep engine is not bit-identical on a TPU (emulated
-    float64), so an explicit `backend="xla"` must refuse to run there and
-    `auto` must pick an exact engine that matches the scalar search."""
+def replay_phase(ts):
+    """The batched capacity search on the chip's host must run the native
+    C replay and match the scalar search bitwise."""
     from repro.core.search import batched_max_sustainable_qps
     from repro.traffic import max_sustainable_qps
     from repro.traffic.cost_table import DEFAULT_HW
 
     tm, slo = _slo()
     tables = [ts.table(a, h, w) for a in SLO_ARCHS for h, w in DEFAULT_HW]
-    refused = ""
-    try:
-        batched_max_sustainable_qps(tables, [tm] * len(tables), slo,
-                                    n_requests=1200, backend="xla")
-    except RuntimeError as e:
-        refused = str(e)
-    _require("not bit-identical" in refused, "xla engine ran on the TPU")
     stats = {}
     bat = batched_max_sustainable_qps(tables, [tm] * len(tables), slo,
                                       n_requests=1200, backend="auto",
@@ -180,11 +171,11 @@ def lockstep_phase(ts):
     bitwise = bool(np.array_equal(q_bat, q_seq)
                    and np.array_equal(s_bat, s_seq, equal_nan=True))
     err = max(_max_rel(q_seq, q_bat), _max_rel(s_seq, s_bat))
-    _require(stats["backend"] != "xla", f"auto picked {stats}")
+    _require(stats["backend"] == "native", f"auto picked {stats}")
     _require(bitwise,
              f"{stats['backend']} vs scalar differ: max rel {err:.3e}")
-    return {"lanes": len(tables), "xla_refused": True,
-            "auto_engine": stats["backend"], "rounds": stats["rounds"],
+    return {"lanes": len(tables), "auto_engine": stats["backend"],
+            "rounds": stats["rounds"],
             "max_rel_vs_scalar": f"{err:.3e}", "bitwise": bitwise}
 
 
@@ -235,7 +226,7 @@ def main():
     _phase("b grid_sweep", grid_phase)
     _phase("c cost_tables", lambda: cost_table_phase(out))
     _phase("d slo_capacity_sweep", lambda: slo_phase(out["tables"]))
-    _phase("e lockstep", lambda: lockstep_phase(out["tables"]))
+    _phase("e replay", lambda: replay_phase(out["tables"]))
     _phase("e nsga2_device", nsga2_phase)
     _phase("e refiner", refiner_phase)
     print(json.dumps({"ok": True, "device": info}))

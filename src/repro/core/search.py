@@ -13,8 +13,8 @@ vectorized probe per bisection round:
     advances its own bracket while all lanes share a single batched
     replay. The probe SEQUENCE each lane sees is identical to the scalar
     search, and the replays themselves are bit-identical
-    (`traffic.lockstep` / `traffic.native`), so the resulting max-QPS
-    tables match the sequential sweep bit for bit.
+    (`traffic.native`), so the resulting max-QPS tables match the
+    sequential sweep bit for bit.
   * `_TraceFactory` amortizes trace sampling: Poisson probes at different
     rates reuse one cached set of exponential/length draws and rebuild
     only the arrival cumsum (draw-for-draw what
@@ -22,12 +22,10 @@ vectorized probe per bisection round:
     processes that consume rate-dependent entropy (mmpp) take the full
     sampler per probe; for mmpp its sojourn loop runs in C on the
     Generator's own bit stream (`traffic.native`).
-  * `_ServerBatch` owns the packed lane engine: fixed tables, persistent
-    request buffers edited in place between rounds, retired lanes parked
-    on trivial length-1 traces (XLA shapes are jit-static — shrinking the
-    batch would recompile). The native C executor is preferred when a
-    compiler is present; the XLA lockstep engine (off-TPU only) and the
-    scalar simulator are fallbacks. All three produce identical numbers.
+  * `_ServerBatch` picks the replay engine for a batch of fixed tables:
+    the native C replay (`traffic.native.NativeBatch`, one call per
+    round) when a compiler is present, and the scalar simulator, its
+    reference, otherwise. Both produce identical numbers.
 
 `nsga2_device` and `refine_design_point` move the other two search loops
 of the DSE onto the device: a fixed-shape NSGA-2 whose jnp generation
@@ -229,85 +227,63 @@ class _TraceFactory:
 
 # ------------------------------------------------------- packed executor ----
 
-_IDLE = "__idle__"
-
-
 class _ServerBatch:
-    """Fixed-lane packed probe executor: one server (cost table) per lane,
-    one shared `SimConfig`, persistent request buffers. Each round takes
-    `{lane: trace}` jobs for the lanes that want a probe; idle lanes are
-    parked on a trivial 1-request trace (the batch shape is jit-static,
-    so the lane count never changes between rounds).
+    """Fixed-lane probe executor: one server (cost table) per lane, one
+    shared `SimConfig`. Each round takes `{lane: trace}` jobs for the
+    lanes that want a probe.
 
     Backend selection (`auto`): the runtime-compiled C replay
     (`traffic.native`) when a compiler is present and the config fits its
-    limits, else the XLA lockstep engine where it is exact (not on a TPU
-    backend, where an explicit "xla" raises), else the scalar simulator.
-    Every backend is bit-identical to `traffic.sim.simulate` per lane."""
+    limits, else the scalar simulator (an explicit "native" raises
+    instead). Both are bit-identical to `traffic.sim.simulate` per lane."""
 
     def __init__(self, tables: Sequence, cfg: SimConfig, n_max: int,
                  backend: str = "auto"):
         self.tables = list(tables)
         self.cfg = cfg
-        self.n_max = int(n_max)
         self.backend = self._resolve(backend)
-        L = len(self.tables)
         if self.backend == "native":
             from repro.traffic.native import NativeBatch
-            self._batch = NativeBatch(self.tables, cfg, self.n_max)
-            self._req = np.empty((L, 3, self.n_max), np.float64)
-        elif self.backend == "xla":
-            from repro.traffic.lockstep import LockstepBatch
-            self._batch = LockstepBatch(self.tables, cfg, self.n_max)
-            self._req = np.empty((L, 3, self.n_max + 1), np.float64)
-        if self.backend != "scalar":
-            self._req[:, 0, :] = np.inf
-            self._req[:, 0, 0] = 0.0
-            self._req[:, 1:, :] = 1.0
-            self._n = np.ones(L, np.int64)
-            self._dirty: set = set()
+            self._batch = NativeBatch(self.tables, cfg, n_max)
 
     def _resolve(self, backend: str) -> str:
         if backend == "scalar":
             return "scalar"
-        if backend not in ("auto", "native", "xla"):
+        if backend not in ("auto", "native"):
             raise ValueError(f"unknown backend {backend!r} "
-                             "(have auto|native|xla|scalar)")
+                             "(have auto|native|scalar)")
         tr = self.cfg.tracer
         if tr is not None and tr.enabled:
-            return "scalar"                # packed engines emit no events;
-                                           # traced replays take the
-                                           # instrumented scalar path
+            return "scalar"                # the packed engine emits no
+                                           # events; traced replays take
+                                           # the instrumented scalar path
         if self.cfg.policy != "prefill_first":
-            return "scalar"                # packed engines only do prefill_first
+            return "scalar"                # the packed engine only does
+                                           # prefill_first
         if self.cfg.prefix_cache_mib is not None or self.cfg.spec is not None:
             return "scalar"                # KV-reuse / speculative replays
                                            # run the scalar event loop
         if self.cfg.windows is not None:
-            return "scalar"                # packed engines keep no
+            return "scalar"                # the packed engine keeps no
                                            # windowed telemetry
         shapes = {(len(t.slot_lattice), len(t.kv_lattice),
                    len(t.prompt_lattice)) for t in self.tables}
         if len(shapes) != 1:
-            return "scalar"                # lattice shapes are jit-static
-        if backend in ("auto", "native"):
-            from repro.traffic import native
-            if native.available() and self.cfg.slots <= 64:
-                return "native"
-            if backend == "native":
-                raise RuntimeError(
-                    "native backend requested but unavailable "
-                    "(no C compiler, or slots > 64)")
-        from repro.traffic.lockstep import exact_on_backend
-        if backend == "auto" and not exact_on_backend():
-            return "scalar"                # emulated f64 would drift
-        return "xla"
+            return "scalar"                # one lattice shape per batch
+        from repro.traffic import native
+        if native.available() and self.cfg.slots <= 64:
+            return "native"
+        if backend == "native":
+            raise RuntimeError(
+                "native backend requested but unavailable "
+                "(no C compiler, or slots > 64)")
+        return "scalar"
 
     def run_round(self, jobs: Dict[int, RequestTrace]
                   ) -> Dict[int, SimResult]:
         """Replay one round (span `search.replay`: packing and the packed
-        engine, or the scalar simulator) and, on a packed engine, build
-        the per-lane results (span `search.score`). A packed engine
+        engine, or the scalar simulator) and, on the packed engine, build
+        the per-lane results (span `search.score`). The packed engine
         counts the round's requests in `sim.requests`, as the scalar
         simulator does per replay (but no `sim.replays`)."""
         t0 = time.perf_counter()
@@ -316,31 +292,12 @@ class _ServerBatch:
             with tr_obs.span("search.replay", "bisect"):
                 return {i: simulate(self.tables[i], tr, self.cfg)
                         for i, tr in jobs.items()}
-        req, n = self._req, self._n
         with tr_obs.span("search.replay", "bisect"):
-            for i in self._dirty - jobs.keys():  # park lanes just retired
-                req[i, 0, :] = np.inf
-                req[i, 0, 0] = 0.0
-                n[i] = 1
-            self._dirty = set(jobs)
-            for i, tr in jobs.items():
-                k = len(tr)
-                n[i] = k
-                req[i, 0, :k] = tr.arrival_s
-                req[i, 0, k:] = np.inf
-                req[i, 1, :k] = tr.prompt_len
-                req[i, 1, k:] = 1.0
-                req[i, 2, :k] = tr.output_len
-                req[i, 2, k:] = 1.0
-            if self.backend == "native":
-                res = self._batch.run_packed(req, n)
-            else:
-                res = self._batch.run_packed(req.reshape(req.shape[0], -1),
-                                             n)
+            res = self._batch.replay(jobs)
         wall = time.perf_counter() - t0
         _obs_metrics().add_many(
             {"sim.requests": sum(len(tr) for tr in jobs.values())})
-        from repro.traffic.lockstep import _to_result
+        from repro.traffic.native import _to_result
         with tr_obs.span("search.score", "bisect"):
             return {i: _to_result(self.tables[i], tr, self.cfg, res, i,
                                   wall)

@@ -1,14 +1,12 @@
-"""Optional cc-compiled replay executor and MMPP sampler for the search.
+"""The capacity search's fast replay engine, and the MMPP sampler, in C.
 
-`core.search` replays hundreds of (design point, probe) lanes per sweep.
-The XLA lockstep engine (`traffic.lockstep`) amortizes Python dispatch
-across lanes, but on a small host its per-iteration launch overhead
-bounds the win; a plain C transcription of the scalar event loop runs a
-replay in microseconds. This module compiles that transcription ONCE per
-process with the system C compiler (no third-party deps — `ctypes` +
-`cc`) and exposes it behind the same packed-lane interface as
-`lockstep.LockstepBatch`, so the search driver can treat the two as
-interchangeable probe executors.
+`core.search` replays hundreds of (design point, probe) lanes per
+bisection round. A plain C transcription of the scalar event loop
+(`traffic.sim.simulate`, `prefill_first` policy) runs one such replay in
+microseconds. This module compiles it ONCE per process with the system C
+compiler (no third-party deps: `ctypes` + `cc`) and runs a round of
+lanes in one call (`NativeBatch`); `_to_result` turns a lane of the
+round back into a `SimResult`.
 
 Bit-identity: the C source is an op-for-op transcription of
 `traffic.sim.simulate`'s prefill_first path, and x86-64/AArch64 doubles
@@ -17,11 +15,12 @@ additionally forbids contracting `a*b + c` into a single-rounding fma,
 so every expression rounds exactly like the interpreted source. The heap
 of (finish_step, rid) pairs becomes a linear scan over packed int64 keys
 `finish_step * (n+1) + rid`, whose minimum reproduces the heap's
-lexicographic pop order (same device trick as `lockstep`).
+lexicographic pop order. The engine runs on the host CPU whatever JAX's
+backend is, so it is exact on a TPU host too.
 
 Everything degrades gracefully: if no C compiler is present or the
 compile fails, `available()` returns False and callers fall back to the
-XLA or scalar paths. The shared object is cached under the system temp
+scalar simulator. The shared object is cached under the system temp
 directory keyed by source hash.
 
 The module also builds, as a separate shared object, the MMPP arrival
@@ -46,10 +45,10 @@ from typing import Dict, Optional, Sequence
 import numpy as np
 
 from repro.core.model_core import DRAM_COST_PER_WORD, REF_BITS
-from repro.traffic.sim import SimConfig
+from repro.traffic.sim import SimConfig, SimResult
 from repro.traffic.workload import RequestTrace
 
-_KPAD = 8                       # lattice pad, shared with lockstep
+_KPAD = 8                       # lattice axes padded to this (with +inf)
 
 _C_SOURCE = r"""
 #include <stdint.h>
@@ -292,46 +291,82 @@ def available() -> bool:
     return _lib is not None
 
 
+def _pack_tables(tables: Sequence[object]):
+    """The per-lane table arrays `replay_lanes` reads: `lat` (L, 3*_KPAD),
+    each lattice +inf-padded to `_KPAD`; the cost grid (L, 2*np + 2*nb*nk)
+    of [prefill cycles | prefill energy | decode cycles | decode energy];
+    `kvb` (L,); and the lattice shape (nb, nk, np) all lanes share."""
+    dims = {(len(t.slot_lattice), len(t.kv_lattice), len(t.prompt_lattice))
+            for t in tables}
+    if len(dims) != 1:
+        raise ValueError(f"one lattice shape per batch, got {sorted(dims)}")
+    (nb, nk, npr), = dims
+    if max(nb, nk, npr) > _KPAD:
+        raise ValueError(f"lattice axes longer than {_KPAD} unsupported")
+    L = len(tables)
+    lat = np.full((L, 3, _KPAD), np.inf)
+    grid = np.empty((L, 2 * npr + 2 * nb * nk))
+    kvb = np.empty(L)
+    for i, tb in enumerate(tables):
+        lat[i, 0, :nb] = tb.slot_lattice
+        lat[i, 1, :nk] = tb.kv_lattice
+        lat[i, 2, :npr] = tb.prompt_lattice
+        grid[i] = np.concatenate([
+            np.asarray(tb.prefill_cycles, np.float64),
+            np.asarray(tb.prefill_energy, np.float64),
+            np.asarray(tb.decode_cycles, np.float64).ravel(),
+            np.asarray(tb.decode_energy, np.float64).ravel()])
+        kvb[i] = tb.kv_bits_per_token
+    return lat.reshape(L, 3 * _KPAD), grid, kvb, (nb, nk, npr)
+
+
 class NativeBatch:
-    """`lockstep.LockstepBatch`-shaped probe executor backed by the C
-    replay loop. Same packed-lane protocol: fixed tables, per-round
-    traces, raw result dict with ttft/tpot plus aggregate vectors."""
+    """A reusable lane batch over FIXED tables: the table arrays are
+    packed once, then `replay` runs many probe rounds that differ only in
+    their traces (the capacity bisection's access pattern: same design
+    points, fresh arrivals per probe). The (L, 3, n_max) request buffer
+    [arrivals | prompt | output] persists between rounds and is edited in
+    place; arrivals past a lane's live length are +inf."""
 
     def __init__(self, tables: Sequence[object], cfg: SimConfig,
                  n_max: int):
-        from repro.traffic.lockstep import _pack_tables
-
         if not available():
             raise RuntimeError("no C compiler available")
         if cfg.policy != "prefill_first":
             raise ValueError("NativeBatch supports prefill_first only")
         if cfg.slots > 64:
             raise ValueError("NativeBatch supports at most 64 slots")
-        self.tables = list(tables)
         self.cfg = cfg
         self.n_max = int(n_max)
-        packed = _pack_tables(tables)
-        self.dims = packed["dims"]
-        self._lat = np.ascontiguousarray(packed["lat"].reshape(
-            len(tables), 3 * _KPAD))
-        nb, nk, npr = self.dims
-        # native grid keeps the raw (unconcatenated-lattice) layout
-        gw = 2 * npr + 2 * nb * nk
-        self._grid = np.ascontiguousarray(
-            packed["sg"][:, 3 * _KPAD:3 * _KPAD + gw])
-        self._kvb = np.ascontiguousarray(packed["kvb"])
+        self._lat, self._grid, self._kvb, self.dims = _pack_tables(tables)
+        L = len(tables)
+        self._req = np.empty((L, 3, self.n_max), np.float64)
+        self._req[:, 0, :] = np.inf
+        self._req[:, 0, 0] = 0.0
+        self._req[:, 1:, :] = 1.0
+        self._n = np.ones(L, np.int64)
+        self._dirty: set = set()
 
-    def run(self, traces: Sequence[RequestTrace]) -> Dict[str, np.ndarray]:
-        from repro.traffic.lockstep import _pack_traces
-
-        # native rows need no +1 scratch column: repack at width n_max
-        req1, n = _pack_traces(traces, self.n_max)
-        req = np.ascontiguousarray(
-            req1.reshape(len(traces), 3, self.n_max + 1)[:, :, :-1])
-        return self.run_packed(req, n)
-
-    def run_packed(self, req: np.ndarray, n: np.ndarray
-                   ) -> Dict[str, np.ndarray]:
+    def replay(self, jobs: Dict[int, RequestTrace]
+               ) -> Dict[str, np.ndarray]:
+        """One round: lane i replays `jobs[i]`; a lane without a job is
+        parked on a trivial 1-request trace. Returns ttft/tpot
+        (L, n_max) and the per-lane aggregate vectors."""
+        req, n = self._req, self._n
+        for i in self._dirty - jobs.keys():      # park lanes just retired
+            req[i, 0, :] = np.inf
+            req[i, 0, 0] = 0.0
+            n[i] = 1
+        self._dirty = set(jobs)
+        for i, tr in jobs.items():
+            k = len(tr)
+            n[i] = k
+            req[i, 0, :k] = tr.arrival_s
+            req[i, 0, k:] = np.inf
+            req[i, 1, :k] = tr.prompt_len
+            req[i, 1, k:] = 1.0
+            req[i, 2, :k] = tr.output_len
+            req[i, 2, k:] = 1.0
         cfg = self.cfg
         L = req.shape[0]
         nb, nk, npr = self.dims
@@ -345,15 +380,38 @@ class NativeBatch:
             float(cfg.ub_kib) * 8192.0 if has_ub else 0.0,
             float(cfg.dram_bits_per_cycle),
             DRAM_COST_PER_WORD / REF_BITS,
-            self._lat, self._grid, self._kvb,
-            np.ascontiguousarray(req.reshape(L, -1)),
-            np.ascontiguousarray(n), ttft, tpot, agg)
+            self._lat, self._grid, self._kvb, req.reshape(L, -1), n,
+            ttft, tpot, agg)
         return {"ttft": ttft, "tpot": tpot, "t": agg[:, 0],
                 "nstep": agg[:, 1].astype(np.int64),
                 "tokens_out": agg[:, 2].astype(np.int64),
                 "decode_seconds": agg[:, 3], "prefill_seconds": agg[:, 4],
                 "spill_seconds": agg[:, 5], "energy": agg[:, 6],
                 "max_step": agg[:, 7]}
+
+
+_EMPTY_TIMELINE = np.empty((0, 3), np.float64)
+
+
+def _to_result(table, trace: RequestTrace, cfg: SimConfig,
+               res: Dict[str, np.ndarray], lane: int,
+               wall: float) -> SimResult:
+    """Assemble one lane of a packed round into a scalar-shaped
+    SimResult (no timeline; `wall` is the whole round's)."""
+    k = len(trace)
+    return SimResult(
+        n=k, arch=table.arch, h=table.h, w=table.w, policy=cfg.policy,
+        slots=cfg.slots, ttft_s=res["ttft"][lane, :k].copy(),
+        tpot_s=res["tpot"][lane, :k].copy(),
+        sim_seconds=float(res["t"][lane]), wall_seconds=wall,
+        offered_qps=trace.offered_qps,
+        tokens_out=int(res["tokens_out"][lane]),
+        decode_steps=int(res["nstep"][lane]),
+        decode_seconds=float(res["decode_seconds"][lane]),
+        prefill_seconds=float(res["prefill_seconds"][lane]),
+        spill_seconds=float(res["spill_seconds"][lane]),
+        max_step_seconds=float(res["max_step"][lane]),
+        energy_eq1=float(res["energy"][lane]), timeline=_EMPTY_TIMELINE)
 
 
 # ------------------------------------------------------ MMPP arrival sampler --

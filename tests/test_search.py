@@ -1,6 +1,7 @@
 """Device-resident search tests (`core.search`): lockstep bisection is
 probe-for-probe the scalar search, batched capacity tables are bit-identical
-to sequential sweeps across every replay backend, the jnp NSGA-2 matches
+to sequential sweeps on both replay engines (native C and scalar), with
+and without KV spills, the jnp NSGA-2 matches
 the numpy oracle bitwise, warm-started frontiers dominate cold ones, and
 the gradient refiner is never-worse than its seed under exact re-evaluation
 (hypothesis property)."""
@@ -97,58 +98,85 @@ def _summaries_equal(a, b):
             assert va == vb, (k, va, vb)
 
 
-@pytest.mark.parametrize("arrival", ["poisson", "mmpp", "mmpp_fallback"])
-def test_batched_capacity_bit_identical(monkeypatch, arrival):
-    """Batched == sequential search. `mmpp_fallback` runs the batched
-    search on the Python MMPP loop against a sequential search on the
-    compiled sampler (where it built): the same (qps, summary)."""
-    from repro.traffic import native
+def _lanes():
     ts = _tables()
-    tm = TrafficModel(arrival=arrival.split("_")[0])
-    slo = SLO(ttft_s=2.0, tpot_s=0.1)
-    sim = SimConfig()
-    tables = [ts.table(a, h, w) for a in ARCHS for h, w in HW]
-    traffics = [tm] * len(tables)
-    seq = [max_sustainable_qps(t, tr, slo, sim=sim, n_requests=200, seed=0)
-           for t, tr in zip(tables, traffics)]
+    return [ts.table(a, h, w) for a in ARCHS for h, w in HW]
+
+
+def _sequential(tm, sim):
+    return [max_sustainable_qps(t, tm, SLO(ttft_s=2.0, tpot_s=0.1),
+                                sim=sim, n_requests=200, seed=0)
+            for t in _lanes()]
+
+
+def _batched_engine(seq, backend, tm, sim):
+    """Assert the batched search on `backend` returns `seq`, the
+    sequential search's (qps, summary), on every lane; return the engine
+    that ran it."""
+    tables = _lanes()
+    stats = {}
+    bat = batched_max_sustainable_qps(tables, [tm] * len(tables),
+                                      SLO(ttft_s=2.0, tpot_s=0.1), sim=sim,
+                                      n_requests=200, seed=0,
+                                      backend=backend, stats=stats)
+    assert len(bat) == len(seq)
+    for (q0, s0), (q1, s1) in zip(seq, bat):
+        assert q0 == q1
+        _summaries_equal(s0, s1)
+    return stats["backend"]
+
+
+def _skip_without_native(backend):
+    from repro.traffic import native
+    if backend == "native" and not native.available():
+        pytest.skip("the native replay engine did not build (no C compiler)")
+
+
+@pytest.mark.parametrize("arrival", ["poisson", "mmpp", "mmpp_fallback"])
+@pytest.mark.parametrize("backend", ["native", "scalar"])
+def test_batched_capacity_bit_identical(monkeypatch, backend, arrival):
+    """Batched == sequential search on each engine. `mmpp_fallback` runs
+    the batched search on the Python MMPP loop against a sequential
+    search on the compiled sampler (where it built): the same (qps,
+    summary)."""
+    from repro.traffic import native
+    _skip_without_native(backend)
+    tm, sim = TrafficModel(arrival=arrival.split("_")[0]), SimConfig()
+    seq = _sequential(tm, sim)
     if arrival == "mmpp_fallback":
         monkeypatch.setattr(native, "mmpp_sampler", lambda: None)
-    for backend in ("xla", "scalar"):
-        bat = batched_max_sustainable_qps(tables, traffics, slo, sim=sim,
-                                          n_requests=200, seed=0,
-                                          backend=backend)
-        for (q0, s0), (q1, s1) in zip(seq, bat):
-            assert q0 == q1
-            _summaries_equal(s0, s1)
+    assert _batched_engine(seq, backend, tm, sim) == backend
 
 
-@pytest.mark.parametrize("backend", ["auto", "xla"])
+@pytest.mark.parametrize("backend", ["native", "scalar"])
+def test_batched_capacity_bit_identical_finite_ub(backend):
+    """A finite unified buffer: the danube lanes spill KV to DRAM (the
+    engines' `has_ub` branch), the xlstm lanes hold no KV and never do."""
+    _skip_without_native(backend)
+    tm, sim = TrafficModel(), SimConfig(ub_kib=65536.0)
+    seq = _sequential(tm, sim)
+    assert _batched_engine(seq, backend, tm, sim) == backend
+    spill = [s["spill_frac_of_decode"] for _, s in seq]
+    assert any(f > 0.0 for f in spill) and any(f == 0.0 for f in spill)
+
+
+@pytest.mark.parametrize("backend", ["auto", "native"])
 def test_tpu_backend_never_picks_lockstep(monkeypatch, backend):
-    """On a TPU backend the x64 lockstep engine is not bit-identical to
-    the scalar reference (emulated float64): `auto` without the native
-    executor takes the scalar path, and an explicit "xla" raises."""
+    """On a TPU backend without the native engine (no C compiler) the
+    packed search runs no XLA replay: `auto` takes the scalar path with
+    the sequential search's answers, and an explicit "native" raises."""
     import jax
 
     from repro.traffic import native
-    ts = _tables()
-    tables = [ts.table(a, h, w) for a in ARCHS for h, w in HW]
-    tm = TrafficModel()
-    slo = SLO(ttft_s=2.0, tpot_s=0.1)
     monkeypatch.setattr(native, "available", lambda: False)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    if backend == "xla":
-        with pytest.raises(RuntimeError, match="not bit-identical"):
-            batched_max_sustainable_qps(tables, [tm] * len(tables), slo,
-                                        n_requests=200, backend=backend)
+    tm, sim = TrafficModel(), SimConfig()
+    seq = _sequential(tm, sim)
+    if backend == "native":
+        with pytest.raises(RuntimeError, match="native backend requested"):
+            _batched_engine(seq, backend, tm, sim)
         return
-    stats = {}
-    bat = batched_max_sustainable_qps(tables, [tm] * len(tables), slo,
-                                      n_requests=200, backend=backend,
-                                      stats=stats)
-    assert stats["backend"] == "scalar"
-    seq = [max_sustainable_qps(t, tm, slo, n_requests=200, seed=0)
-           for t in tables]
-    assert [q for q, _ in bat] == [q for q, _ in seq]
+    assert _batched_engine(seq, backend, tm, sim) == "scalar"
 
 
 def test_slo_sweep_batched_equals_sequential():

@@ -12,7 +12,6 @@ reason.
 """
 import os
 
-import numpy as np
 import pytest
 
 import jax
@@ -25,9 +24,7 @@ from repro.core.dse import grid_axes
 from repro.kernels.dse_eval import CONFIG_TILE, dse_eval, dse_eval_batched
 from repro.traffic.cost_table import (DEFAULT_HW, DEFAULT_KV_LATTICE,
                                       DEFAULT_PROMPT_LATTICE,
-                                      DEFAULT_SLOT_LATTICE, _lattice_shapes,
-                                      build_cost_tables)
-from repro.traffic.sim import SimConfig
+                                      DEFAULT_SLOT_LATTICE, _lattice_shapes)
 
 
 @pytest.fixture(scope="module")
@@ -119,27 +116,3 @@ def test_batched_sweep_kernel_compiles_scenario_shape(one_chip,
         _spec((_grid_configs(), 2), jnp.float32, one_chip),
         _spec((32, 9, 5), jnp.float32, one_chip), interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
-
-
-def test_lockstep_engine_compiles_yi9b_lanes(one_chip, no_compile_cache):
-    """The x64 multi-lane replay engine for one yi-9b lane per DEFAULT_HW
-    config at the capacity sweep's 1200-request probes."""
-    from repro.traffic import lockstep
-
-    ts = build_cost_tables(archs=["yi-9b"], backend="numpy")
-    tables = [ts.table("yi-9b", h, w) for h, w in DEFAULT_HW]
-    packed = lockstep._pack_tables(tables)
-    dims = packed.pop("dims")
-    cfg = SimConfig()
-    n_max = 1200
-    L = len(tables)
-    with jax.enable_x64(True):
-        static = {k: _spec(v.shape, v.dtype, one_chip)
-                  for k, v in packed.items()}
-        scal = {k: _spec((), np.float64, one_chip)
-                for k in ("zero", "clock")}
-        compiled = lockstep._engine(cfg.slots, cfg.ub_kib is not None,
-                                    dims).lower(
-            static, _spec((L, 3 * (n_max + 1)), np.float64, one_chip),
-            _spec((L,), np.int64, one_chip), scal).compile()
-    assert compiled.memory_analysis() is not None
