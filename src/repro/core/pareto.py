@@ -5,12 +5,31 @@ from __future__ import annotations
 
 import numpy as np
 
+from repro.obs.metrics import metrics as _obs_metrics
+
 
 def pareto_mask(objectives: np.ndarray) -> np.ndarray:
     """objectives: (n, k), all MINIMIZED. Returns bool mask of the frontier:
     the points that no point dominates (no worse in every objective and
-    better in one), from all n x n comparisons at once."""
+    better in one).
+
+    Two objectives (k = 2): one lexicographic sort and one sweep,
+    O(n log n) (`_pareto_mask_2d`), counted as `pareto.masks_sorted`. Any
+    other k: all n x n comparisons at once, O(n^2 k) time and two n x n
+    boolean temporaries (`_pareto_mask_all_pairs`), counted as
+    `pareto.masks_all_pairs`. Both return the same mask: a row with a NaN
+    compares false against every row, so it stays on the frontier and
+    dominates nothing; equal rows do not dominate each other and both
+    stay; -0.0 equals 0.0 and +-inf compare as numbers."""
     F = np.asarray(objectives)
+    if F.shape[1] == 2:
+        _obs_metrics().inc("pareto.masks_sorted")
+        return _pareto_mask_2d(F)
+    _obs_metrics().inc("pareto.masks_all_pairs")
+    return _pareto_mask_all_pairs(F)
+
+
+def _pareto_mask_all_pairs(F: np.ndarray) -> np.ndarray:
     n = F.shape[0]
     no_worse = np.ones((n, n), bool)       # [i, j]: point j <= point i
     better = np.zeros((n, n), bool)
@@ -18,6 +37,27 @@ def pareto_mask(objectives: np.ndarray) -> np.ndarray:
         no_worse &= f[None, :] <= f[:, None]
         better |= f[None, :] < f[:, None]
     return ~(no_worse & better).any(1)
+
+
+def _pareto_mask_2d(F: np.ndarray) -> np.ndarray:
+    """Sort the rows without a NaN by (f0, f1) and group equal f0: a row is
+    dominated when a group of smaller f0 reaches its f1 or below, or its
+    own group holds a smaller f1."""
+    keep = np.ones(F.shape[0], bool)
+    rows = np.flatnonzero(~np.isnan(F).any(1))
+    if rows.size < 2:
+        return keep
+    rows = rows[np.lexsort((F[rows, 1], F[rows, 0]))]
+    f0, f1 = F[rows, 0], F[rows, 1]
+    first = np.r_[True, f0[1:] != f0[:-1]]     # -0.0 == 0.0: one group
+    group = np.cumsum(first) - 1
+    group_min = f1[first]                      # f1 ascends in a group
+    # least f1 over the groups before each one (no NaN is left to poison it)
+    below = np.minimum.accumulate(group_min)
+    dominated = (group_min[group] < f1) | (
+        (group > 0) & (below[group - 1] <= f1))
+    keep[rows[dominated]] = False
+    return keep
 
 
 def fast_non_dominated_sort(F: np.ndarray) -> np.ndarray:

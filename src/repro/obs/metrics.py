@@ -37,6 +37,8 @@ Counter catalog (the names the stack emits; see README "Observability"):
     search.probes              lane-probes served by those rounds
     sample.mmpp_native         MMPP traces drawn by the compiled sampler
     sample.mmpp_fallback       MMPP traces drawn by the Python loop
+    pareto.masks_sorted        two-objective Pareto masks, by sort and sweep
+    pareto.masks_all_pairs     other Pareto masks, by n x n comparison
 """
 from __future__ import annotations
 

@@ -5,8 +5,8 @@ from _hyp import given, settings, st
 
 from repro.core import (ZOO, equal_pe_sweep, get_workloads, grid_sweep,
                         pareto_grid, robust_config)
-from repro.core.pareto import (crowding_distance, fast_non_dominated_sort,
-                               nsga2, pareto_mask)
+from repro.core.pareto import (_pareto_mask_all_pairs, crowding_distance,
+                               fast_non_dominated_sort, nsga2, pareto_mask)
 from repro.core.workloads import total_macs
 
 
@@ -183,6 +183,59 @@ def test_pareto_mask_matches_the_point_by_point_loop(n, k, seed):
     for i in range(n):
         dom = (F <= F[i]).all(1) & (F < F[i]).any(1)
         want[i] = not (dom & want).any()
+    assert pareto_mask(F).tolist() == want.tolist()
+
+
+def _fig5_robust_F():
+    """The 961-point F of `robust_serving_config` over the paper's nine
+    CNNs under one seeded Dirichlet mix."""
+    from repro.core.dse import robust_serving_config, scenario_sweep
+    names = ("alexnet", "vgg16", "googlenet", "bn_inception", "resnet152",
+             "resnext152_32x4d", "densenet201", "mobilenetv3_large",
+             "efficientnet_b0")
+    sweep = scenario_sweep({n: get_workloads(n) for n in names},
+                           backend="numpy")
+    weights = np.random.default_rng(5).dirichlet(np.ones(len(names)))
+    return robust_serving_config(sweep, dict(zip(names, weights)))[1]
+
+
+def _with_nan(*cols):
+    """Tie-heavy rows with NaN in some of `cols`; with both, the first
+    rows hold NaN in both."""
+    rng = np.random.default_rng(len(cols) + cols[0])
+    F = rng.integers(0, 5, (120, 2)).astype(float)
+    for c in cols:
+        F[rng.uniform(size=120) < 0.15, c] = np.nan
+    F[:3, list(cols)] = np.nan
+    return F
+
+
+_PARETO_2D_CASES = {
+    **{f"ties-n{n}": (lambda n=n: np.random.default_rng(n).integers(
+        0, 5, (n, 2))) for n in (1, 2, 3, 9, 40, 117, 200)},
+    "duplicates": lambda: np.repeat(np.random.default_rng(1).integers(
+        0, 5, (30, 2)).astype(float), 3, axis=0),
+    "infinities": lambda: np.random.default_rng(2).choice(
+        [-np.inf, 0.0, 1.0, 2.0, np.inf], (150, 2)),
+    "signed-zero": lambda: np.random.default_rng(3).choice(
+        [-0.0, 0.0, 1.0, -1.0], (80, 2)),
+    "nan-col0": lambda: _with_nan(0),
+    "nan-col1": lambda: _with_nan(1),
+    "nan-both": lambda: _with_nan(0, 1),
+    "all-nan": lambda: np.full((4, 2), np.nan),
+    "empty": lambda: np.zeros((0, 2)),
+    "single": lambda: np.array([[3.0, np.inf]]),
+    "fig5-robust-F": _fig5_robust_F,
+}
+
+
+@pytest.mark.parametrize("case", list(_PARETO_2D_CASES))
+def test_pareto_mask_2d_sweep_equals_all_pairs(case):
+    """The sorted two-objective frontier is the all-pairs mask, point for
+    point: ties, duplicates, infinities, signed zeros, NaN rows (kept,
+    dominating nothing), n = 0 and 1, and the Fig. 5 objectives."""
+    F = _PARETO_2D_CASES[case]()
+    want = _pareto_mask_all_pairs(F)
     assert pareto_mask(F).tolist() == want.tolist()
 
 

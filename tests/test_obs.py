@@ -283,6 +283,25 @@ def test_robust_config_traces_its_normalization():
         obs.set_tracer(old)
 
 
+def test_robust_normalization_takes_the_sorted_pareto_mask():
+    """A robust query's two objectives take the sorted frontier, once;
+    three objectives take the all-pairs comparison."""
+    from repro.core import get_workloads
+    from repro.core.dse import robust_serving_config, scenario_sweep
+    from repro.core.pareto import pareto_mask
+    axis = np.array([64, 160, 256])
+    sweep = scenario_sweep({n: get_workloads(n) for n in ("alexnet", "vgg16")},
+                           hs=axis, ws=axis, backend="numpy")
+    before = obs.metrics().snapshot()
+    robust_serving_config(sweep)
+    d = obs.metrics().delta(before)
+    assert d.get("pareto.masks_sorted") == 1
+    assert "pareto.masks_all_pairs" not in d
+    before = obs.metrics().snapshot()
+    pareto_mask(np.random.default_rng(0).uniform(size=(20, 3)))
+    assert obs.metrics().delta(before) == {"pareto.masks_all_pairs": 1}
+
+
 def test_build_stage_tables_is_one_fused_dispatch():
     from repro.fleet.partition import build_stage_tables
     before = obs.metrics().snapshot()
